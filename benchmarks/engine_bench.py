@@ -111,6 +111,7 @@ from repro.core.strategies import (ReptileStrategy, TifedStrategy,
 from repro.data import SineTasks
 from repro.models.paper_nets import (init_paper_model, paper_model_loss,
                                      relu_mlp_loss)
+from repro.runtime.compile_cache import enable_compile_cache
 
 LOSS = functools.partial(paper_model_loss, SINE_MLP)
 ROUNDS = 120
@@ -217,7 +218,7 @@ def mesh_scaling(rounds: int = ROUNDS, smoke: bool = False):
     """The mesh_scaling section: rounds/sec for cohort size x device
     count, sharding the client axis over the devices THIS process has
     (run under XLA_FLAGS=--xla_force_host_platform_device_count=8 on
-    CPU; ``bench`` spawns that subprocess automatically when the parent
+    CPU; ``bench`` spawns that subprocess automatically when a CPU parent
     has a single device). devices=1 is the legacy mesh=None engine —
     the strongest single-device baseline. Acceptance floors (see
     docs/BENCHMARKS.md): >= 2x rounds/sec at cohort 64 on 8 host
@@ -286,30 +287,32 @@ def mesh_scaling(rounds: int = ROUNDS, smoke: bool = False):
     return rows, section
 
 
-def _mesh_scaling_subprocess(rounds: int, devices: int = 8):
-    """Run ``mesh_scaling`` in a child process with forced host devices
-    (the device count is fixed at backend init, so the parent cannot
-    grow its own); returns the section dict."""
+def _forced_device_child(flag: str, rounds: int, devices: int):
+    """Run one multi-device section (``--mesh-only`` / ``--lm-mesh-only``)
+    in a child process with ``devices`` forced host devices (the device
+    count is fixed at backend init, so the parent cannot grow its own)
+    and return the section dict. CPU parents only: a chip belongs to the
+    process that holds it, so off the CPU a child could never reach it.
+    A failed child raises, failing the whole run."""
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(f"{flag} child processes are for CPU parents; "
+                           f"on {jax.default_backend()} run the section "
+                           f"in-process")
     env = dict(os.environ)
     flags = [f for f in env.get("XLA_FLAGS", "").split()
              if "host_platform_device_count" not in f]
     env["XLA_FLAGS"] = " ".join(
         flags + [f"--xla_force_host_platform_device_count={devices}"])
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
-        [sys.executable, "-m", "benchmarks.engine_bench", "--mesh-only",
+        [sys.executable, "-m", "benchmarks.engine_bench", flag,
          "--rounds", str(rounds)],
         capture_output=True, text=True, env=env,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     if r.returncode != 0:
-        return {"status": "FAILED", "stderr": r.stderr[-2000:]}
-    try:
-        # tolerate stray non-JSON stdout from the child's imports: the
-        # section object is the last thing printed, starting at its
-        # opening brace
-        return json.loads(r.stdout[r.stdout.index("{"):])
-    except (ValueError, json.JSONDecodeError):
-        return {"status": "FAILED",
-                "stderr": f"unparseable child stdout: {r.stdout[-2000:]!r}"}
+        raise RuntimeError(f"{flag} child failed:\n{r.stderr[-2000:]}")
+    # the section object is the last thing printed, from its brace on
+    return json.loads(r.stdout[r.stdout.index("{"):])
 
 
 def lm_mesh_bench(rounds: int = ROUNDS, smoke: bool = False):
@@ -395,28 +398,6 @@ def lm_mesh_bench(rounds: int = ROUNDS, smoke: bool = False):
             f"must be <= 0.6x the replicated 1-D layout, got "
             f"{ratio:.3f} ({phi_bytes})")
     return rows, section
-
-
-def _lm_mesh_subprocess(rounds: int, devices: int = 4):
-    """Run ``lm_mesh_bench`` in a child with forced host devices (the
-    _mesh_scaling_subprocess pattern); returns the section dict."""
-    env = dict(os.environ)
-    flags = [f for f in env.get("XLA_FLAGS", "").split()
-             if "host_platform_device_count" not in f]
-    env["XLA_FLAGS"] = " ".join(
-        flags + [f"--xla_force_host_platform_device_count={devices}"])
-    r = subprocess.run(
-        [sys.executable, "-m", "benchmarks.engine_bench",
-         "--lm-mesh-only", "--rounds", str(rounds)],
-        capture_output=True, text=True, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    if r.returncode != 0:
-        return {"status": "FAILED", "stderr": r.stderr[-2000:]}
-    try:
-        return json.loads(r.stdout[r.stdout.index("{"):])
-    except (ValueError, json.JSONDecodeError):
-        return {"status": "FAILED",
-                "stderr": f"unparseable child stdout: {r.stdout[-2000:]!r}"}
 
 
 def serving_bench(smoke: bool = False):
@@ -809,28 +790,30 @@ def bench(rounds: int = ROUNDS, smoke: bool = False):
                  f"overhead_pct={overhead_pct:.2f}"))
     budget.check("ckpt_overhead")
 
-    # -- mesh scaling: shard the client axis over (forced) host devices --
-    # Multi-device parents (the multi-device CI job, a real accelerator
-    # host) sweep in-process; a single-device full run spawns the forced
-    # 8-device subprocess; a single-device SMOKE run skips the section
-    # (tier-1 time budget — the dedicated multi-device CI job covers it).
-    if len(jax.devices()) > 1:
+    # -- mesh scaling: shard the client axis over the devices ------------
+    # Multi-device parents and accelerator hosts sweep in-process (on an
+    # accelerator a section without enough devices fails the run); a
+    # single-device CPU full run spawns the forced 8-device child; a
+    # single-device CPU SMOKE run skips the section (tier-1 time budget —
+    # the dedicated multi-device CI job covers it).
+    on_cpu = jax.default_backend() == "cpu"
+    if len(jax.devices()) > 1 or not on_cpu:
         mesh_rows, results["mesh_scaling"] = mesh_scaling(rounds, smoke)
         rows.extend(mesh_rows)
     elif not smoke:
-        results["mesh_scaling"] = _mesh_scaling_subprocess(rounds)
+        results["mesh_scaling"] = _forced_device_child("--mesh-only",
+                                                       rounds, 8)
     budget.check("mesh_scaling")
 
     # -- lm_mesh: the 2-D (clients x model) mesh on a transformer (PR 10) --
-    # >= 4 devices sweep in-process (the mesh2d CI job forces 4 on CPU);
-    # a single-device full run spawns the forced-device subprocess; a
-    # single-device smoke skips (tier-1 time budget — the mesh2d job
+    # same rule with >= 4 devices (the mesh2d CI job forces 4 on CPU and
     # runs --lm-mesh-only --smoke, which arms the 0.6x bytes floor).
-    if len(jax.devices()) >= 4:
+    if len(jax.devices()) >= 4 or not on_cpu:
         lm_rows, results["lm_mesh"] = lm_mesh_bench(rounds, smoke)
         rows.extend(lm_rows)
     elif not smoke:
-        results["lm_mesh"] = _lm_mesh_subprocess(rounds)
+        results["lm_mesh"] = _forced_device_child("--lm-mesh-only",
+                                                  rounds, 4)
     budget.check("lm_mesh")
 
     # -- serving: the continuous-batching adaptation server (PR 9) ------
@@ -879,6 +862,7 @@ def main():
                          "mesh2d CI job's fast path, where --smoke arms "
                          "the 0.6x per-device parameter bytes floor")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.mesh_only:
         _, section = mesh_scaling(rounds=args.rounds)

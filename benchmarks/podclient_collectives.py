@@ -112,22 +112,30 @@ def measure():
 
 
 def run():
-    """Benchmark-driver entry: runs in a subprocess (needs 512 devices)."""
+    """``benchmarks.run`` entry: a 512-host-device CPU compile, run in a
+    child process because the device count is fixed at backend init.
+    CPU parents only (a child cannot reach a chip its parent holds); a
+    failed child raises."""
     import subprocess
     import sys
+
+    import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError("podclient_collectives compiles for 512 forced "
+                           "host devices in a child process; run it from a "
+                           "CPU parent (JAX_PLATFORMS=cpu)")
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-m",
                         "benchmarks.podclient_collectives"],
                        capture_output=True, text=True, env=env, timeout=2400)
-    rows = []
     if r.returncode != 0:
-        return [("podclient/error", 0.0, r.stderr[-120:])]
+        raise RuntimeError(f"podclient child failed:\n{r.stderr[-2000:]}")
     d = json.loads(r.stdout.strip().splitlines()[-1])
-    for mode, (intra, cross) in d.items():
-        rows.append((f"podclient/{mode}", 0.0,
-                     f"intra_pod={intra/1e6:.1f}MB cross_pod={cross/1e6:.1f}MB"))
-    return rows
+    return [(f"podclient/{mode}", 0.0,
+             f"intra_pod={intra/1e6:.1f}MB cross_pod={cross/1e6:.1f}MB")
+            for mode, (intra, cross) in d.items()]
 
 
 if __name__ == "__main__":

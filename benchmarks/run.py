@@ -30,6 +30,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="substring filter on module names")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for modname in MODULES:

@@ -51,7 +51,8 @@ dispatch per client per round. This module owns all of that once:
 * The server update routes through the fused Pallas kernel
   (``repro.kernels.ops.meta_update``) by default on TPU backends;
   elsewhere the same fp32 math runs as plain XLA (the kernel would only
-  interpret there).
+  interpret there), and so it does on 2-D meshes, whose GSPMD-partitioned
+  blocks cannot hold a Mosaic kernel (``resolve_use_pallas``).
 * ``run_federated(..., mesh=...)`` SHARDS THE CLIENT AXIS across a
   device mesh: the block runner wraps its scan in ``shard_map`` (manual
   over a 1-D "clients" mesh axis), each device vmaps over its local
@@ -76,11 +77,13 @@ otherwise pin up to 64 stale executables).
 from __future__ import annotations
 
 import collections
+import contextlib
 import copy
 import dataclasses
 import inspect
 import logging
 import math
+import threading
 from typing import Dict, List, Optional
 
 import jax
@@ -98,7 +101,6 @@ from repro.core.pipeline import (ClientSchedule, SamplingPolicy,
 from repro.core.pool import (BufferedAggregation, ClientPool, PoolState,
                              pool_state_specs)
 from repro.data.tasks import TaskDistribution
-from repro.runtime.sharding import shard_map_compat
 
 logger = logging.getLogger(__name__)
 
@@ -111,9 +113,36 @@ CLIENT_AXIS = "clients"
 PAYLOAD_ITEMSIZE = {"float32": 4, "float16": 2, "int8": 1}
 
 
-def default_use_pallas() -> bool:
-    """Pallas server update only where it compiles natively."""
-    return jax.default_backend() == "tpu"
+#: set while a GSPMD-partitioned (2-D mesh) block body traces
+_GSPMD_TRACE = threading.local()
+
+
+@contextlib.contextmanager
+def _gspmd_trace():
+    prev = getattr(_GSPMD_TRACE, "on", False)
+    _GSPMD_TRACE.on = True
+    try:
+        yield
+    finally:
+        _GSPMD_TRACE.on = prev
+
+
+def resolve_use_pallas(use_pallas: Optional[bool] = None) -> bool:
+    """A ``use_pallas`` option as given, or, for None, whether the Pallas
+    kernels compile natively here (a TPU backend; elsewhere they would
+    only interpret). Inside a 2-D mesh's GSPMD block body the answer is
+    the XLA route: the compiler cannot partition a Mosaic kernel over
+    model-sharded operands, so an explicit True is refused there."""
+    if getattr(_GSPMD_TRACE, "on", False):
+        if use_pallas:
+            raise ValueError(
+                "use_pallas=True on a model-sharded mesh: Mosaic kernels "
+                "cannot be partitioned automatically; leave use_pallas at "
+                "None there")
+        return False
+    if use_pallas is None:
+        return jax.default_backend() == "tpu"
+    return use_pallas
 
 
 def client_mesh(devices=None) -> Mesh:
@@ -176,9 +205,7 @@ def meta_interpolate(phi, phi_hat, alpha, *, use_pallas: Optional[bool] = None):
     """Reptile server update phi <- phi + alpha (phi_hat - phi), fp32 math,
     cast back to each leaf's storage dtype. Routed through the fused Pallas
     kernel when `use_pallas` (default: on TPU)."""
-    if use_pallas is None:
-        use_pallas = default_use_pallas()
-    if use_pallas:
+    if resolve_use_pallas(use_pallas):
         from repro.kernels import ops as kops
         return jax.tree.map(
             lambda p, q: kops.meta_update(p, q, alpha), phi, phi_hat)
@@ -890,10 +917,10 @@ class _BlockRunner:
         # matmuls imply is compiler-scheduled. The weighted client mean
         # then reduces the clients-sharded results axis — one all-reduce
         # with phi's model shards aggregated IN PLACE (no gather of full
-        # phi to any device). The per-round partial-manual shard_map form
-        # (manual over "clients", auto over "model") is what
-        # shard_map_compat was built for, but XLA's partitioner in this
-        # toolchain hard-aborts (CHECK sharding.IsManualSubgroup) on
+        # phi to any device). A per-round partial-manual shard_map form
+        # (manual over "clients", auto over "model") would be the
+        # alternative, but XLA's partitioner in this toolchain
+        # hard-aborts (CHECK sharding.IsManualSubgroup) on
         # scan-emitting-outputs under vmap inside a manual subgroup —
         # strategy hooks are user-pluggable, so that pattern cannot be
         # outlawed. Pure GSPMD keeps both invariants (zero per-round
@@ -915,6 +942,17 @@ class _BlockRunner:
         else:
             def pin_phi(phi):
                 return phi
+
+        def gspmd_body(body):
+            # GSPMD cannot partition a Mosaic kernel, so on a 2-D mesh the
+            # strategy hooks trace their XLA route (resolve_use_pallas)
+            if not model_sharded:
+                return body
+
+            def traced(*args):
+                with _gspmd_trace():
+                    return body(*args)
+            return traced
 
         if pooled:
             if axis is not None:
@@ -960,14 +998,14 @@ class _BlockRunner:
                     # per-round losses were shard-local partial sums
                     return phi, pool_state, jax.lax.psum(losses, axis)
 
-            body = block_body
+            body = gspmd_body(block_body)
             if axis is not None:
-                body = shard_map_compat(
+                body = jax.shard_map(
                     block_body, mesh=mesh,
                     in_specs=(P(), state_spec, sched_spec(),
                               P(None, axis)),
                     out_specs=(P(), state_spec, P()),
-                    manual_axes_names={axis})
+                    check_vma=False)
 
             def run_block(phi, pool_state, sched, batch):
                 self.trace_count += 1             # runs at trace time only
@@ -986,13 +1024,13 @@ class _BlockRunner:
                     losses = jax.lax.psum(losses, axis)
                 return pin_phi(phi), losses
 
-            body = block_body
+            body = gspmd_body(block_body)
             if axis is not None:
-                body = shard_map_compat(
+                body = jax.shard_map(
                     block_body, mesh=mesh,
                     in_specs=(P(), sched_spec(), P(None, axis)),
                     out_specs=(P(), P()),
-                    manual_axes_names={axis})
+                    check_vma=False)
 
             def run_block(phi, sched, batch):
                 self.trace_count += 1             # runs at trace time only

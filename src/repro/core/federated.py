@@ -24,9 +24,8 @@ aggregation hook (``reptile_aggregate_weighted(..., axis_name="pod")``:
 each pod contributes weight 1/n_pods and the weighted client mean
 all-reduces across the pod axis — exactly the masked-psum form the
 client-sharded engine uses over its "clients" axis, see
-``run_federated(mesh=...)``). shard_map (manual over "pod", GSPMD auto
-over ("data","model") inside) comes from the shared
-``repro.runtime.sharding.shard_map_compat``.
+``run_federated(mesh=...)``). ``jax.shard_map`` is manual over "pod"
+and leaves ("data","model") to GSPMD inside.
 """
 from __future__ import annotations
 
@@ -35,11 +34,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-
-# re-exported: shard_map_compat historically lived here; it is now the
-# shared wrapper in repro.runtime.sharding (the round engine's
-# client-sharded block runner uses it too)
-from repro.runtime.sharding import shard_map_compat  # noqa: F401
 
 
 def make_pod_client_meta_step(model, mesh, *, beta: float = 0.01,
@@ -50,14 +44,6 @@ def make_pod_client_meta_step(model, mesh, *, beta: float = 0.01,
     if "pod" not in mesh.axis_names:
         raise ValueError("pod-client mode needs the multi-pod mesh")
 
-    # Partial-auto shard_map (manual "pod", GSPMD auto data/model) needs
-    # the modern jax.shard_map; the experimental fallback miscompiles
-    # partial-manual subgroups (XLA CHECK IsManualSubgroup), so there we
-    # go fully manual: every device in a pod computes the pod's whole
-    # client batch (replicated instead of data-sharded) — identical
-    # numerics, just without intra-pod data parallelism.
-    partial_auto = hasattr(jax, "shard_map")
-    manual = ("pod",) if partial_auto else tuple(mesh.axis_names)
     n_pods = mesh.shape["pod"]
 
     def round_body(phi, batch, alpha_t):
@@ -67,7 +53,7 @@ def make_pod_client_meta_step(model, mesh, *, beta: float = 0.01,
         from repro.core.strategies import reptile_aggregate_weighted
         from repro.runtime.shardctx import manual_axes
 
-        with manual_axes(*manual):
+        with manual_axes("pod"):
             # the engine's inner loop: one SGD step per arriving
             # microbatch, fp32 update math
             phi_hat, losses = streaming_sgd(model.loss_fn, phi, batch,
@@ -99,9 +85,9 @@ def make_pod_client_meta_step(model, mesh, *, beta: float = 0.01,
             P(),
         )
         out_specs = (jax.tree.map(lambda x: P(), phi), P())
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             round_body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            manual_axes_names=set(manual))
+            axis_names={"pod"}, check_vma=False)
         return fn(phi, batch, jnp.asarray(alpha_t, jnp.float32))
 
     return step

@@ -54,7 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.engine import meta_interpolate
+from repro.core.engine import meta_interpolate, resolve_use_pallas
 from repro.core.meta import (finetune_batch, finetune_batch_masked,
                              finetune_online, finetune_online_masked)
 from repro.kernels import ref as kref
@@ -499,9 +499,7 @@ class TifedStrategy(FedStrategy):
                 jnp.exp2((TIFED_SERR - sacc[i] - lrs).astype(f32))
                 for i in range(3)),
         }
-        use_pallas = (jax.default_backend() == "tpu"
-                      if self.use_pallas is None else self.use_pallas)
-        if use_pallas:
+        if resolve_use_pallas(self.use_pallas):
             from repro.kernels import ops as kops
             epoch_fn = kops.dfa_epoch_int8
             init = (tuple(w.astype(jnp.int8) for w in ws),

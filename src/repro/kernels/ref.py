@@ -56,6 +56,13 @@ DFA_SHIFT = 7                 # feedback projections are scaled by 2^-7
 _DN = (((0,), (0,)), ((), ()))   # contract the sample axis; vmap batches
 
 
+def _dot(a, b, dims):
+    # HIGHEST: the default TPU precision is one bf16 pass, which drops
+    # bits of integer products past 2^8 and breaks the exactness above
+    return jax.lax.dot_general(a, b, dims,
+                               precision=jax.lax.Precision.HIGHEST)
+
+
 def pow2_exponent(maxabs, limit=INT8_MAX):
     """Smallest power-of-two exponent e with maxabs * 2^-e <= limit.
 
@@ -117,13 +124,16 @@ def dfa_int8_epoch(ws, bs, xq, yal, layer, fb, dither, scales):
     fb1, fb2 = fb
     d0_, d1_, d2_ = dither
 
-    z0 = (xq * w0 if w0.shape[0] == 1 else xq @ w0) + b0
+    def mm(a, b):
+        return _dot(a, b, (((1,), (0,)), ((), ())))
+
+    z0 = (xq * w0 if w0.shape[0] == 1 else mm(xq, w0)) + b0
     a1 = jnp.clip(jnp.round(jnp.maximum(z0, 0.0) * scales["f0"]),
                   0.0, INT8_MAX)
-    z1 = a1 @ w1 + b1
+    z1 = mm(a1, w1) + b1
     a2 = jnp.clip(jnp.round(jnp.maximum(z1, 0.0) * scales["f1"]),
                   0.0, INT8_MAX)
-    z2 = a2 @ w2 + b2
+    z2 = mm(a2, w2) + b2
     err = z2 - yal
     eq = jnp.clip(jnp.round(err * scales["fe"]), -INT8_MAX, INT8_MAX)
     loss = jnp.sum(jnp.square(err)) * scales["floss"]
@@ -132,12 +142,12 @@ def dfa_int8_epoch(ws, bs, xq, yal, layer, fb, dither, scales):
     def proj(fbm):
         # error fed straight back to the hidden layer; dout==1 is a
         # broadcast, larger heads contract the output axis
-        return eq * fbm if fbm.shape[0] == 1 else eq @ fbm
+        return eq * fbm if fbm.shape[0] == 1 else mm(eq, fbm)
 
     def hidden_update(i, z, a_in, fbm, dith, c):
         d = jnp.round(jnp.where(z > 0, proj(fbm), 0.0) * 2.0 ** -DFA_SHIFT)
         g = ((a_in * d).sum(0, keepdims=True) if a_in.shape[1] == 1
-             else jax.lax.dot_general(a_in, d, _DN))
+             else _dot(a_in, d, _DN))
         w = jnp.clip(c[i] - stochastic_round(g * ftw[i], dith),
                      -INT8_MAX, INT8_MAX)
         b = jnp.clip(c[3 + i] - jnp.round(d.sum(0) * ftb[i]),
@@ -152,7 +162,7 @@ def dfa_int8_epoch(ws, bs, xq, yal, layer, fb, dither, scales):
         return hidden_update(1, z1, a1, fb2, d1_, c)
 
     def u2(c):
-        g = jax.lax.dot_general(a2, eq, _DN)
+        g = _dot(a2, eq, _DN)
         w = jnp.clip(c[2] - stochastic_round(g * ftw[2], d2_),
                      -INT8_MAX, INT8_MAX)
         b = jnp.clip(c[5] - jnp.round(eq.sum(0) * ftb[2]),

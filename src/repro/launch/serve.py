@@ -220,6 +220,8 @@ def run_adapt(args):
 
 def main(argv=None):
     args = parse_args(argv)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "decode":
         run_decode(args)
     else:

@@ -33,6 +33,9 @@ def run_cell(arch, shape, multi_pod, outdir, timeout=3000):
         cmd.append("--multi-pod")
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # the dry run compiles for 512 forced HOST devices: each child stays
+    # on the CPU, so none of them ever contends for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     t0 = time.time()
     try:
         r = subprocess.run(cmd, capture_output=True, text=True,

@@ -56,6 +56,7 @@ from repro.core.pool import (DiurnalAvailability, MarkovAvailability,
 from repro.data import LMClientStream
 from repro.models import build_model
 from repro.optim.schedules import linear_anneal
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.sharding import init_distributed
 from repro.runtime.steps import (make_meta_train_step, microbatch,
                                  prefetch_batches)
@@ -436,6 +437,7 @@ def run_engine_strategy(args):
 
 
 def main():
+    enable_compile_cache()
     args = parse_args()
     if args.strategy != "tinyreptile":
         return run_engine_strategy(args)

@@ -156,38 +156,10 @@ def init_distributed(coordinator: str, num_processes: int,
         raise ValueError(f"process_id={process_id} out of range for "
                          f"num_processes={num_processes}")
     if num_processes > 1:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except (AttributeError, ValueError):
-            pass        # older jaxlib: flag absent; TPU/GPU don't need it
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=num_processes,
                                    process_id=process_id)
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, manual_axes_names):
-    """Version-portable shard_map: manual over `manual_axes_names`, GSPMD
-    auto over every other mesh axis.
-
-    Newer JAX exposes ``jax.shard_map(..., axis_names=...)`` (manual axes
-    named directly); older releases only have
-    ``jax.experimental.shard_map.shard_map(..., auto=...)`` (auto axes
-    named, i.e. the complement). Resolve whichever exists.
-
-    Shared by the pod-client mode (repro.core.federated, manual over
-    "pod") and the round engine's client-sharded block runner
-    (repro.core.engine, manual over "clients").
-    """
-    manual = frozenset(manual_axes_names)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False,
-                             axis_names=set(manual))
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - manual
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False, auto=auto)
 
 
 def _axes(mesh):

@@ -35,14 +35,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.engine import resolve_use_pallas
 from repro.core.strategies import (TIFED_ACT, TIFED_EX, TIFED_SERR,
                                    _tifed_constants)
 from repro.kernels import ref as kref
 from repro.models.paper_nets import relu_mlp_loss
-
-
-def _default_use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,9 +67,7 @@ class Fp32Adapter:
         y = jax.lax.dynamic_index_in_dim(slot["sy"], i, keepdims=False)
         batch = {"x": x[None], "y": y[None]}
         loss, g = jax.value_and_grad(self.loss_fn)(slot["params"], batch)
-        use_pallas = (_default_use_pallas() if self.use_pallas is None
-                      else self.use_pallas)
-        if use_pallas:
+        if resolve_use_pallas(self.use_pallas):
             from repro.kernels import ops as kops
             params = kops.tree_online_sgd(slot["params"], g,
                                           jnp.float32(self.lr))
@@ -170,9 +165,7 @@ class TifedAdapter:
         dither = tuple(
             jax.lax.dynamic_index_in_dim(d, e, keepdims=False)
             for d in pack["dith"])
-        use_pallas = (_default_use_pallas() if self.use_pallas is None
-                      else self.use_pallas)
-        if use_pallas:
+        if resolve_use_pallas(self.use_pallas):
             from repro.kernels import ops as kops
             epoch_fn = kops.dfa_epoch_int8
             cw = tuple(w.astype(jnp.int8) for w in slot["cw"])

@@ -22,7 +22,8 @@ Covers the tentpole contracts:
   INSIDE a federated 2-D round (REPRO_OPT_SSD_PALLAS routes the
   prefetcher-thread trace; interpret mode on CPU), with parity
   against the oracle einsum route;
-- validation: int8 strategies rejected on model-sharded meshes,
+- validation: int8 strategies and an explicit Pallas server update
+  (use_pallas=True) rejected on model-sharded meshes,
   partitioner= rejected without a 2-D mesh, and partitioner identity
   as part of the runner-cache key.
 """
@@ -234,6 +235,14 @@ try:
     raise SystemExit("oversized mesh accepted")
 except ValueError:
     pass
+# GSPMD cannot partition a Mosaic kernel: the 2-D route refuses an
+# explicit Pallas server update (None resolves to XLA there)
+try:
+    run_federated(params, dist, TinyReptileStrategy(LOSS, use_pallas=True),
+                  mesh=mesh2d, **kw)
+    raise SystemExit("Pallas server update on a model-sharded mesh accepted")
+except ValueError as e:
+    assert "model-sharded" in str(e)
 
 S = TinyReptileStrategy(LOSS, use_pallas=None)
 clear_runner_cache()
