@@ -89,6 +89,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint.ckpt import (AsyncCheckpointWriter, RoundState,
@@ -602,7 +603,14 @@ class _BlockRunner:
         needs_ref = getattr(channel, "needs_uplink_ref", False)
         partial = getattr(channel, "fraction", 1.0) < 1.0
         rotating = partial and bool(getattr(channel, "rotate", False))
+        # the server update's device ops carry this scope in their HLO
+        # metadata, whichever of the scan bodies below calls it
+        server_aggregate = jax.named_scope("server_update")(
+            strategy.server_aggregate)
+        server_aggregate_weighted = jax.named_scope("server_update")(
+            strategy.server_aggregate_weighted)
 
+        @jax.named_scope("client_phase")
         def client_phase(phi, sched, batch, masks, chunk_ids):
             """Downlink -> vmapped client hook -> uplink: the wire-and-
             compute half of a round, shared by every scan body."""
@@ -654,7 +662,7 @@ class _BlockRunner:
                     results, losses = client_phase(phi, sched, batch,
                                                    masks, chunk_ids)
                     if axis is not None:
-                        phi = strategy.server_aggregate_weighted(
+                        phi = server_aggregate_weighted(
                             phi, results, sched.alpha, beta_f,
                             sched.weights, axis_name=axis)
                         # the round loss stays a SHARD-LOCAL partial sum
@@ -664,13 +672,13 @@ class _BlockRunner:
                         # rendezvous every round
                         loss = weighted_round_loss(losses, sched)
                     elif scheduled:
-                        phi = strategy.server_aggregate_weighted(
+                        phi = server_aggregate_weighted(
                             phi, results, sched.alpha, beta_f,
                             sched.weights)
                         loss = weighted_round_loss(losses, sched)
                     else:
-                        phi = strategy.server_aggregate(phi, results,
-                                                        sched.alpha, beta_f)
+                        phi = server_aggregate(phi, results,
+                                               sched.alpha, beta_f)
                         loss = jnp.mean(losses)
                     return phi, loss
 
@@ -712,7 +720,7 @@ class _BlockRunner:
                     results, losses = client_phase(phi, sched, batch,
                                                    masks, chunk_ids)
                     if buffered is None:
-                        phi = strategy.server_aggregate_weighted(
+                        phi = server_aggregate_weighted(
                             phi, results, sched.alpha, beta_f,
                             sched.weights)
                         buf, buf_round = ps.buf_updates, ps.buf_round
@@ -743,7 +751,7 @@ class _BlockRunner:
                                  * (jnp.arange(cap) < count))
                             w = (w / jnp.maximum(w.sum(), 1e-8)
                                  ).astype(jnp.float32)
-                            phi = strategy.server_aggregate_weighted(
+                            phi = server_aggregate_weighted(
                                 phi, buf, sched.alpha, beta_f, w)
                             return phi, jnp.int32(0), flushes + 1
 
@@ -797,7 +805,7 @@ class _BlockRunner:
                     part_f = packed[:, c_local:].reshape(-1) > 0
 
                     if buffered is None:
-                        phi = strategy.server_aggregate_weighted(
+                        phi = server_aggregate_weighted(
                             phi, results, sched.alpha, beta_f,
                             sched.weights, axis_name=axis)
                         buf, buf_round = ps.buf_updates, ps.buf_round
@@ -837,7 +845,7 @@ class _BlockRunner:
                             denom = jax.lax.psum(w.sum(), axis)
                             w = (w / jnp.maximum(denom, 1e-8)
                                  ).astype(jnp.float32)
-                            phi = strategy.server_aggregate_weighted(
+                            phi = server_aggregate_weighted(
                                 phi, buf, sched.alpha, beta_f, w,
                                 axis_name=axis)
                             return phi, jnp.int32(0), flushes + 1
@@ -1260,541 +1268,568 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
     config fingerprint.
 
     `tracker` attaches a `repro.metering.MetricsTracker`: per-round
-    inner losses, cumulative transport bytes, eval rows, runner-cache /
-    wall-clock gauges, and (pooled runs) the end-of-run staleness
-    distribution flow into it, and a tracker with `profile_dir=` set
-    brackets the scan loop in the JAX profiler. The tracker is
-    host-side observation only — attaching one is bit-for-bit inert
-    (the per-block loss fetch happens ONLY when a tracker is present,
-    and feeds nothing back).
+    inner losses, cumulative transport bytes, eval rows, runner-cache
+    gauges, and (pooled runs) the end-of-run staleness distribution
+    flow into it. The tracker is host-side observation only — attaching
+    one is bit-for-bit inert (the per-block loss fetch happens ONLY when
+    a tracker is present, and feeds nothing back).
+
+    `jax.profiler.trace(dir)` around the call records the engine's
+    spans (`engine.run` and its per-block phases, listed in
+    repro.metering.tracker) on the device trace's clock; with no
+    profiler session running they cost about a microsecond each.
     """
-    if channel is None:
-        channel = CommChannel()
-    if sampling is None:
-        # a pooled run's host-path contract is the POOL's sampler: a
-        # vectorized (fleet-scale) pool must also seat cohorts through
-        # the O(cohort) block path, not the per-round O(N) choice loop
-        if pool is not None and sampler == "reference":
-            sampling = UniformSampling(pool.sampler)
-        else:
-            sampling = UniformSampling(sampler)
-    elif sampler != "reference":
-        # an explicit policy owns its own sampler choice; silently
-        # ignoring a non-default `sampler=` string would run a different
-        # host path than the caller asked for
-        raise ValueError(
-            f"pass the sampler on the sampling policy (e.g. "
-            f"{type(sampling).__name__}(..., sampler={sampler!r})), not "
-            f"as run_federated(sampler=...) alongside sampling=")
-    pooled = pool is not None
-    if buffered is not None:
-        if not pooled:
-            raise ValueError("buffered aggregation needs persistent "
-                             "clients to be stale against: pass "
-                             "pool=ClientPool(...) alongside buffered=")
-        if getattr(strategy, "uplink_ref", "params") == "none":
-            raise ValueError(
-                f"{type(strategy).__name__} uplinks raw data "
-                f"(uplink_ref='none'); the FedBuff buffer holds "
-                f"phi-shaped updates and cannot stage it")
-    if pooled and pool.size < clients_per_round:
-        raise ValueError(f"pool of {pool.size} clients cannot seat a "
-                         f"cohort of {clients_per_round} (identities are "
-                         f"unique within a round)")
-    payload_dtype = getattr(strategy, "payload_dtype", "float32")
-    if payload_dtype != "float32" and (channel.simulates_quantization
-                                       or channel.dtype != payload_dtype):
-        raise ValueError(
-            f"{type(strategy).__name__} uplinks NATIVE {payload_dtype} "
-            f"result trees (payload_dtype={payload_dtype!r}): the channel "
-            f"must bill at that wire rate and must not re-simulate "
-            f"quantization on already-quantized payloads — pass "
-            f"CommChannel({payload_dtype!r}, quantize=False), got "
-            f"{type(channel).__name__}(dtype={channel.dtype!r}, "
-            f"simulates_quantization={channel.simulates_quantization})")
-    mesh = _resolve_mesh(mesh)
-    # the cohort is split over the CLIENTS axis extent only; on a 2-D
-    # (clients, model) mesh the model axis splits phi's weight
-    # matrices, not the cohort
-    shards = int(mesh.shape[CLIENT_AXIS]) if mesh is not None else 1
-    model_sharded = _model_sharded(mesh)
-    if model_sharded:
-        from repro.runtime.sharding import DEFAULT_PARTITIONER
-        if partitioner is None:
-            partitioner = DEFAULT_PARTITIONER
-        if getattr(strategy, "payload_dtype", "float32") == "int8":
-            raise ValueError(
-                f"{type(strategy).__name__} uplinks NATIVE int8 trees "
-                f"whose per-tensor quantization grids assume each "
-                f"parameter tensor is whole on every device; a 2-D "
-                f"('{CLIENT_AXIS}', '{MODEL_AXIS}') mesh shards phi's "
-                f"weight matrices — run int8 strategies on a 1-D "
-                f"'{CLIENT_AXIS}' mesh (or mesh=None) instead")
-    elif partitioner is not None:
-        raise ValueError(
-            f"partitioner= only applies to a 2-D ('{CLIENT_AXIS}', "
-            f"'{MODEL_AXIS}') mesh (build one with "
-            f"repro.runtime.sharding.client_model_mesh); this run's mesh "
-            f"is {'1-D' if mesh is not None else 'None'} and phi stays "
-            f"replicated")
-    # a mesh spanning >1 process (jax.distributed) changes only HOW
-    # arrays move: every process runs this same host loop on the same
-    # seed (plans, rng draws, and bills are process-replicated), each
-    # contributes its addressable shard at staging, and device->host
-    # reads of client-sharded state go through a replicating collective
-    multiproc = (mesh is not None and
-                 len({d.process_index for d in mesh.devices.flat}) > 1)
-
-    def stage_tree(tree, target):
-        """device_put — or, cross-host, per-leaf global-array assembly
-        from the process-replicated host copy (device_put cannot build
-        an array it only partially addresses)."""
-        if not multiproc:
-            return jax.device_put(tree, target)
-        return jax.tree.map(
-            lambda x, s: jax.make_array_from_callback(
-                np.shape(x), s, lambda idx, _x=np.asarray(x): _x[idx]),
-            tree, target)
-
-    def fetch_tree(tree):
-        """device_get — or, cross-host, an all-gather into replicated
-        form first (client-sharded leaves are not fully addressable
-        from any one process). The gather is a collective: every
-        process calls this at the same points, which the lockstep host
-        loop guarantees."""
-        if not multiproc:
-            return jax.device_get(tree)
-        rep = jax.jit(
-            lambda t: t,
-            out_shardings=jax.tree.map(
-                lambda _: NamedSharding(mesh, P()), tree))(tree)
-        return jax.tree.map(np.asarray, rep)
-    # mesh runs pad the cohort to a multiple of the shard count: the
-    # pad slots are permanently scheduled out (participation False,
-    # weight 0, zero batch) so every device sees an equal shard and the
-    # validity-mask machinery keeps them inert
-    c_pad = -(-clients_per_round // shards) * shards
-    # pool-state LAYOUT: the 1-D manual shard_map body needs the
-    # per-shard layout (per-shard FedBuff slabs, (shards,) local fill
-    # levels); the 2-D GSPMD route runs the GLOBAL body, which sees the
-    # whole state like a flat run does — build the shards == 1 layout
-    # and let the committed input shardings split it
-    state_shards = 1 if model_sharded else shards
-    # residency="host" pools keep the (N,) identity arrays in host
-    # slabs; the device carries only a fixed gathered WINDOW of the
-    # rows each block actually touches (O(block cohort), not O(N)) —
-    # the producer remaps cohort indices window-local, the consumer
-    # stages the window before each block and scatters it back after
-    host_resident = pooled and pool.residency == "host"
-    slabs = pool.init_slabs(shards=state_shards) if host_resident else None
-    rng = np.random.default_rng(seed)
-    # private copy: the block runner donates its phi argument, and the
-    # caller's init_params must stay usable (they are reused across runs)
-    phi = jax.tree.map(jnp.array, init_params)
-    history: List[Dict] = []
-    comm_bytes = 0
-    start_round = 0
-    per_client_bytes = np.zeros(pool.size if pooled else clients_per_round,
-                                np.int64)
-    uniform = getattr(sampling, "schedule_kind", "scheduled") == "uniform"
-    scheduled = pooled or mesh is not None or not uniform
-    # uniform schedules run every client at the full budget, so the
-    # scheduled body skips the per-step lax.cond masking (bit-for-bit
-    # identical at k == budget, without the per-inner-step overhead)
-    masked = scheduled and not uniform
-    budget = int(strategy.local_step_budget(support))
-    run_block = _block_runner(strategy, beta, channel, scheduled,
-                              pooled=pooled, buffered=buffered, mesh=mesh,
-                              masked=masked, partitioner=partitioner)
-    # FedBuff buffers stage whatever the strategy uplinks — sized from
-    # its template so quantized strategies buffer int8 trees at int8
-    # width, never dequantized copies
-    uplink_template = getattr(strategy, "uplink_template", None)
-    pool_state = (pool.init_state(
-        phi, c_pad, buffered, shards=state_shards,
-        template=uplink_template(phi) if uplink_template else None)
-        if pooled else None)
-    if ckpt_dir is not None:
-        if not (isinstance(ckpt_every, int) and ckpt_every >= 1):
-            raise ValueError(f"ckpt_every must be an int >= 1, got "
-                             f"{ckpt_every!r}")
-        if not (isinstance(ckpt_keep, int) and ckpt_keep >= 1):
-            raise ValueError(f"ckpt_keep must be an int >= 1, got "
-                             f"{ckpt_keep!r}")
-        # config identity stamped into every snapshot: a resume under a
-        # different seed/cohort/pool/mesh would replay a DIFFERENT run
-        # from this run's carry — reject it instead of training garbage
-        fingerprint = {
-            "seed": int(seed), "clients_per_round": int(clients_per_round),
-            "support": int(support), "shards": int(shards),
-            # full mesh topology + partitioning identity: a snapshot of
-            # model-sharded (or differently-mesh-shaped) phi must never
-            # silently resume into a run with a different layout
-            "mesh": (",".join(f"{a}:{int(mesh.shape[a])}"
-                              for a in mesh.axis_names)
-                     if mesh is not None else ""),
-            "partitioner": partitioner.name if partitioner is not None
-            else "",
-            "strategy": type(strategy).__name__,
-            "pool_size": int(pool.size) if pooled else 0,
-            "pool_sampler": pool.sampler if pooled else "",
-            "policy_sampler": getattr(sampling, "sampler", "reference"),
-            "buffered": buffered is not None}
-    elif resume:
-        raise ValueError("resume=True needs ckpt_dir= to restore from")
-    if resume:
-        try:
-            saved = restore_round_state(
-                ckpt_dir, phi=phi, pool_state=pool_state,
-                per_client_bytes=per_client_bytes)
-        except FileNotFoundError:
-            logger.info("resume: no snapshot in %s yet; starting fresh",
-                        ckpt_dir)
-            saved = None
-        if saved is not None:
-            diff = {k: (saved.fingerprint.get(k), v)
-                    for k, v in fingerprint.items()
-                    if saved.fingerprint and saved.fingerprint.get(k) != v}
-            if diff:
-                raise ValueError(
-                    f"checkpoint in {ckpt_dir} was written by a different "
-                    f"run config (saved != current): {diff}")
-            if saved.round > rounds:
-                raise ValueError(
-                    f"checkpoint in {ckpt_dir} is at round {saved.round}, "
-                    f"past rounds={rounds}; raise the horizon to continue")
-            start_round = int(saved.round)
-            phi = jax.tree.map(jnp.asarray, saved.phi)
-            if pooled:
-                pool_state = jax.tree.map(jnp.asarray, saved.pool_state)
-                pool.load_host_state(saved.host.get("pool", {}))
-            per_client_bytes = np.asarray(saved.per_client_bytes,
-                                          np.int64).copy()
-            comm_bytes = int(saved.comm_bytes)
-            history = list(saved.history)
-            # the host rng resumes EXACTLY where the interrupted run's
-            # producer stopped drawing — the bit-for-bit contract
-            rng.bit_generator.state = saved.host["rng"]
-            sampling.load_state_dict(saved.host.get("sampling", {}),
-                                     rng=rng)
-            logger.info("resumed %s from round %d", ckpt_dir, start_round)
-    blocks, pad = plan_blocks(rounds, eval_every, max_block,
-                              start=start_round,
-                              ckpt_every=ckpt_every if ckpt_dir else 0)
-    if host_resident:
-        # flush the full (possibly just-restored) identity into the
-        # host slabs, then shrink the device carry to the gathered
-        # window: one row per DISTINCT client a block can seat (a block
-        # has pad rounds of c_pad slots), fixed for the whole run so
-        # the runner still compiles once
-        n_full = len(slabs["last_seen"])
-        pool.scatter_rows(
-            np.arange(n_full),
-            {f: np.asarray(getattr(pool_state, f))
-             for f in ClientPool.SLAB_FIELDS})
-        slab_rows = min(n_full,
-                        -(-pad * c_pad // state_shards) * state_shards)
-        win = pool.init_state(
-            phi, c_pad, buffered, shards=state_shards,
-            template=uplink_template(phi) if uplink_template else None,
-            rows=slab_rows)
-        # identity rows are re-staged from the slabs every block; the
-        # FedBuff buffer is SERVER state and carries over (restored
-        # buffers survive the shrink)
-        pool_state = PoolState(
-            win.last_seen, win.staleness, win.checkins,
-            pool_state.buf_updates, pool_state.buf_round,
-            pool_state.buf_count, pool_state.flushes)
-    if mesh is not None:
-        # 1-D mesh: phi fully replicated. 2-D mesh: each leaf carries
-        # the partitioner's NamedSharding — weight matrices split on
-        # the model axis, norms/biases replicated — and stays that way
-        # through the whole run (aggregation psums over the clients
-        # axis leave the model-axis shards in place; phi is never
-        # gathered whole onto one device)
-        phi = jax.device_put(
-            phi, partitioner.shardings(phi, mesh) if model_sharded
-            else NamedSharding(mesh, P()))
-    if mesh is not None and pooled:
-        # 1-D manual route: rows and FedBuff slabs are device_put in
-        # shard_map's layout. 2-D GSPMD route: the flat-layout state is
-        # staged replicated (rows are O(N) int32, not padded to the
-        # clients extent) and the compiler re-shards inside the block
-        # as the client-phase shardings dictate.
-        pool_state = stage_tree(
-            jax.tree.map(np.asarray, pool_state) if multiproc
-            else pool_state,
-            jax.tree.map(lambda s: NamedSharding(mesh, s),
-                         (jax.tree.map(lambda _: P(), pool_state)
-                          if model_sharded
-                          else pool_state_specs(pool_state, CLIENT_AXIS)),
-                         is_leaf=lambda x: isinstance(x, P)))
-
-    def ckpt_at(end):
-        """Deterministic snapshot predicate, shared by the producer's
-        host-state capture and the consumer's device-state snapshot
-        (plan_blocks cuts blocks at these rounds when ckpt_dir is set)."""
-        return ckpt_dir is not None and (end == rounds
-                                         or end % ckpt_every == 0)
-
-    def snapshot_host():
-        """Host-side carry at 'all draws for blocks <= i done' — called
-        on the prefetch producer right after block i's sampling, so a
-        resume continues the rng/pool/policy streams exactly where the
-        uninterrupted run's producer would."""
-        snap = {"rng": copy.deepcopy(rng.bit_generator.state)}
-        if pooled:
-            snap["pool"] = pool.host_state()
-        policy_state = sampling.state_dict()
-        if policy_state:
-            snap["sampling"] = policy_state
-        return snap
-
-    host_snaps: Dict[int, dict] = {}
-    writer = (AsyncCheckpointWriter(ckpt_dir, keep=ckpt_keep)
-              if ckpt_dir is not None and ckpt_async and blocks else None)
-    device = single_device_of(phi)       # staging target for the prefetcher
-    if strategy.meters_comm:
-        # per-round payloads repeat with the channel's rotation period
-        # (period 1 = the constant legacy accounting)
-        period = (channel.rotation_period
-                  if getattr(channel, "rotate", False) else 1)
-        payload_by_phase = np.array(
-            [channel.payload_bytes_at(init_params, j) for j in range(period)],
-            np.int64)
-
-    def stage(i):
-        """Plan the schedule, sample, pad, and device-stage block i.
-        Called strictly in block order (inline, or from the single
-        prefetch thread), so the host RNG stream is
-        prefetch-schedule-independent: plan_schedule (or its pooled
-        variant) draws first, then the data sampling, every block."""
-        start, end = blocks[i]
-        blk = end - start
-        if pooled:
-            plan = sampling.plan_pool_schedule(rng, start, end,
-                                               clients_per_round, budget,
-                                               pool.size)
-            part = np.asarray(plan["participation"], bool)
-            cohort = np.asarray(plan["cohort"], np.int32)
-            batch = pool.sample_cohort_block(cohort, part, support,
-                                             strategy.data_mode)
-            if host_resident:
-                # remap global cohort ids to window-local rows: the
-                # sorted distinct participants seat the window prefix,
-                # searchsorted inverts the map. Non-participant slots
-                # clamp into range (they are masked in-scan) and
-                # billing keeps the GLOBAL ids.
-                uniq = np.unique(cohort[part]).astype(np.int64)
-                if uniq.size:
-                    local = np.searchsorted(uniq, cohort).astype(np.int32)
-                    np.clip(local, 0, uniq.size - 1, out=local)
-                else:
-                    local = np.zeros_like(cohort)
-                sched_cohort = local
+    with TraceAnnotation("engine.run", rounds=rounds,
+                         clients=clients_per_round,
+                         seed=seed) as run_span:
+        if channel is None:
+            channel = CommChannel()
+        if sampling is None:
+            # a pooled run's host-path contract is the POOL's sampler: a
+            # vectorized (fleet-scale) pool must also seat cohorts through
+            # the O(cohort) block path, not the per-round O(N) choice loop
+            if pool is not None and sampler == "reference":
+                sampling = UniformSampling(pool.sampler)
             else:
-                uniq = None
-                sched_cohort = cohort
-        else:
-            plan = sampling.plan_schedule(rng, start, end,
-                                          clients_per_round, budget)
-            part = np.asarray(plan["participation"], bool)
-            cohort = uniq = sched_cohort = None
-            batch = sampling.sample_block(task_dist, rng, blk,
-                                          clients_per_round, support,
-                                          strategy.data_mode,
-                                          participation=part)
-        r = np.arange(start, end)
-        alphas = np.zeros(pad, np.float32)
-        alphas[:blk] = alpha * (1 - r / rounds) if anneal else alpha
-        valid = np.zeros(pad, bool)
-        # pooled rounds where nobody checked in (an availability trough)
-        # are runtime no-ops, same as the padding mask
-        valid[:blk] = part.any(axis=1) if pooled else True
-        round_index = np.zeros(pad, np.int32)
-        round_index[:blk] = r
-
-        def pad_rows(a, dtype):
-            # pads BOTH axes: short tail blocks on the round axis and
-            # the mesh cohort pad (c_pad == clients_per_round off-mesh)
-            out = np.zeros((pad, c_pad), dtype)
-            out[:blk, :clients_per_round] = a
-            return out
-
-        sched = ClientSchedule(
-            valid=valid, alpha=alphas, round_index=round_index,
-            participation=pad_rows(part, bool),
-            local_steps=pad_rows(plan["local_steps"], np.int32),
-            weights=pad_rows(plan["weights"], np.float32),
-            cohort=pad_rows(sched_cohort, np.int32) if pooled else None)
-        batch = {k: np.asarray(v) for k, v in batch.items()}
-        if c_pad > clients_per_round:
-            batch = {k: np.concatenate(
-                [v, np.zeros((v.shape[0], c_pad - clients_per_round)
-                             + v.shape[2:], v.dtype)], axis=1)
-                for k, v in batch.items()}
-        if blk < pad:
-            batch = {k: np.concatenate(
-                [v, np.zeros((pad - blk,) + v.shape[1:], v.dtype)])
-                for k, v in batch.items()}
-        target = (block_shardings(mesh, CLIENT_AXIS, (sched, batch))
-                  if mesh is not None else device)
-        if ckpt_at(end):
-            host_snaps[end] = snapshot_host()
-        return part, cohort, uniq, stage_tree((sched, batch), target)
-
-    id_sharding = (NamedSharding(mesh, P(CLIENT_AXIS))
-                   if mesh is not None else device)
-
-    def stage_window(uniq):
-        """Gather the block's identity rows from the host slabs onto
-        device (window prefix = the block's distinct participants, tail
-        rows inert). Runs on the CONSUMER, after the previous block's
-        write-back — the prefetch thread never races the slabs."""
-        uniq_pad = np.zeros(slab_rows, np.int64)
-        uniq_pad[:uniq.size] = uniq
-        rows = pool.gather_rows(uniq_pad)
-        rows = tuple(rows[f] for f in ClientPool.SLAB_FIELDS)
-        return stage_tree(rows, (None if id_sharding is None else
-                                 tuple(id_sharding for _ in rows)))
-
-    staged_iter = prefetch_items(stage, len(blocks), depth=prefetch)
-    if tracker is not None:
-        tracker.on_run_start()
-    try:
-        for (start, end), (part, cohort, uniq, staged) in zip(blocks,
-                                                              staged_iter):
-            sched_d, batch_d = staged
-            if host_resident:
-                ls, st, ck = stage_window(uniq)
-                pool_state = PoolState(
-                    ls, st, ck, pool_state.buf_updates,
-                    pool_state.buf_round, pool_state.buf_count,
-                    pool_state.flushes)
-            if pooled:
-                phi, pool_state, round_losses = run_block(
-                    phi, pool_state, sched_d, batch_d)
-            else:
-                phi, round_losses = run_block(phi, sched_d, batch_d)
-            if host_resident and uniq.size:
-                got = fetch_tree(
-                    tuple(getattr(pool_state, f)
-                          for f in ClientPool.SLAB_FIELDS))
-                pool.scatter_rows(
-                    uniq, {f: np.asarray(g)[:uniq.size] for f, g in
-                           zip(ClientPool.SLAB_FIELDS, got)})
-            blk = end - start
-            if tracker is not None:
-                # the loss fetch syncs on the block — done ONLY when a
-                # tracker asks for it, so tracker=None stays fetch-free
-                tracker.on_block(start, end,
-                                 np.asarray(round_losses)[:blk])
-            if strategy.meters_comm:
-                # bill downlink + uplink per participating client, at the
-                # round's exact (possibly rotating) payload
-                payloads = payload_by_phase[
-                    np.arange(start, end) % len(payload_by_phase)]
-                if pooled:
-                    # bill the POOL CLIENT seated in each participating
-                    # slot (np.add.at accumulates repeat check-ins)
-                    bills = 2 * payloads[:, None] * part
-                    np.add.at(per_client_bytes, cohort[part], bills[part])
-                else:
-                    per_client_bytes += (2 * payloads[:, None] * part).sum(0)
-                block_bytes = int((2 * payloads * part.sum(axis=1)).sum())
-                comm_bytes += block_bytes
-                if tracker is not None:
-                    tracker.on_transport(end, block_bytes, comm_bytes)
-            if eval_every and end % eval_every == 0:
-                # cross-host: run the eval protocol on a LOCAL numpy
-                # copy of the replicated phi, so it stays a per-process
-                # computation (identical on every process) instead of a
-                # collective
-                eval_phi = (jax.tree.map(np.asarray, phi) if multiproc
-                            else phi)
-                ev = evaluate_init(strategy.loss_fn, eval_phi, task_dist,
-                                   np.random.default_rng(10_000 + end - 1),
-                                   **(eval_kwargs or {}))
-                ev["round"] = end
-                if strategy.meters_comm:
-                    ev["comm_bytes"] = comm_bytes
-                if strategy.tracks_inner_loss:
-                    ev["inner_loss"] = float(round_losses[blk - 1])
-                history.append(ev)
-                if tracker is not None:
-                    tracker.on_eval(ev)
-            if ckpt_at(end):
-                # block-boundary COPIES: the live carry is donated to
-                # the next block, so the snapshot dispatches a device
-                # copy (async, off the host critical path) and hands
-                # THAT to the writer thread for the D2H transfer
-                # cross-host snapshots materialize to host numpy HERE
-                # (the replicating fetch is a collective every process
-                # must join); single-process runs keep the async device
-                # copy. Only process 0 touches the filesystem.
-                snap_copy = fetch_tree if multiproc else _snapshot_copy
-                if host_resident:
-                    # checkpoints always carry the FULL (N,) layout —
-                    # identity straight from the host slabs (post
-                    # write-back), buffer leaves device-copied — so
-                    # snapshots restore into either residency
-                    pool_snap = PoolState(
-                        *(np.array(slabs[f])
-                          for f in ClientPool.SLAB_FIELDS),
-                        *(snap_copy((
-                            pool_state.buf_updates, pool_state.buf_round,
-                            pool_state.buf_count, pool_state.flushes))))
-                elif pooled:
-                    pool_snap = snap_copy(pool_state)
-                else:
-                    pool_snap = None
-                state = RoundState(
-                    round=end,
-                    phi=(jax.tree.map(np.asarray, phi) if multiproc
-                         else _snapshot_copy(phi)),
-                    pool_state=pool_snap,
-                    per_client_bytes=per_client_bytes.copy(),
-                    comm_bytes=comm_bytes, history=list(history),
-                    host=host_snaps.pop(end), fingerprint=fingerprint)
-                if multiproc and jax.process_index() != 0:
-                    pass                 # peers only joined the fetch
-                elif writer is not None:
-                    writer.submit_state(state)
-                else:
-                    save_round_state(ckpt_dir, state, keep=ckpt_keep)
-        if writer is not None:
-            writer.close()      # drain pending snapshots; surface errors
-    finally:
-        staged_iter.close()
-        if writer is not None:
-            writer.close(raise_errors=False)
-        if tracker is not None:
-            tracker.stop_profile()   # idempotent; covers error exits
-
-    out = {"params": phi, "history": history}
-    if strategy.meters_comm:
-        out["comm_bytes"] = comm_bytes
-        # C-level tolist(), not a per-element int() loop: the bill has
-        # pool.size entries, and a million-client fleet pays ~100ms for
-        # the boxing loop vs ~10ms here
-        out["per_client_bytes"] = per_client_bytes.tolist()
-    if pooled:
-        ps = fetch_tree(pool_state)
-        # [:pool.size] drops the mesh shard-padding rows (a no-op slice
-        # on unsharded runs); host-resident identity reads from the
-        # slabs (the device window only holds the last block's rows)
-        ident = (slabs if host_resident else
-                 {f: getattr(ps, f) for f in ClientPool.SLAB_FIELDS})
-        out["pool_state"] = {
-            f: np.array(ident[f][:pool.size])
-            for f in ClientPool.SLAB_FIELDS}
+                sampling = UniformSampling(sampler)
+        elif sampler != "reference":
+            # an explicit policy owns its own sampler choice; silently
+            # ignoring a non-default `sampler=` string would run a different
+            # host path than the caller asked for
+            raise ValueError(
+                f"pass the sampler on the sampling policy (e.g. "
+                f"{type(sampling).__name__}(..., sampler={sampler!r})), not "
+                f"as run_federated(sampler=...) alongside sampling=")
+        pooled = pool is not None
         if buffered is not None:
-            out["pool_state"]["flushes"] = int(ps.flushes)
-            # scalar off-mesh; per-shard fill levels (shards,) on mesh
-            out["pool_state"]["buffered_pending"] = int(
-                np.asarray(ps.buf_count).sum())
-    if tracker is not None:
-        tracker.on_run_end(
-            runner_cache_stats(),
-            staleness=(out["pool_state"]["staleness"] if pooled else None))
-    return out
+            if not pooled:
+                raise ValueError("buffered aggregation needs persistent "
+                                 "clients to be stale against: pass "
+                                 "pool=ClientPool(...) alongside buffered=")
+            if getattr(strategy, "uplink_ref", "params") == "none":
+                raise ValueError(
+                    f"{type(strategy).__name__} uplinks raw data "
+                    f"(uplink_ref='none'); the FedBuff buffer holds "
+                    f"phi-shaped updates and cannot stage it")
+        if pooled and pool.size < clients_per_round:
+            raise ValueError(f"pool of {pool.size} clients cannot seat a "
+                             f"cohort of {clients_per_round} (identities are "
+                             f"unique within a round)")
+        payload_dtype = getattr(strategy, "payload_dtype", "float32")
+        if payload_dtype != "float32" and (channel.simulates_quantization
+                                           or channel.dtype != payload_dtype):
+            raise ValueError(
+                f"{type(strategy).__name__} uplinks NATIVE {payload_dtype} "
+                f"result trees (payload_dtype={payload_dtype!r}): the channel "
+                f"must bill at that wire rate and must not re-simulate "
+                f"quantization on already-quantized payloads — pass "
+                f"CommChannel({payload_dtype!r}, quantize=False), got "
+                f"{type(channel).__name__}(dtype={channel.dtype!r}, "
+                f"simulates_quantization={channel.simulates_quantization})")
+        mesh = _resolve_mesh(mesh)
+        # the cohort is split over the CLIENTS axis extent only; on a 2-D
+        # (clients, model) mesh the model axis splits phi's weight
+        # matrices, not the cohort
+        shards = int(mesh.shape[CLIENT_AXIS]) if mesh is not None else 1
+        model_sharded = _model_sharded(mesh)
+        if model_sharded:
+            from repro.runtime.sharding import DEFAULT_PARTITIONER
+            if partitioner is None:
+                partitioner = DEFAULT_PARTITIONER
+            if getattr(strategy, "payload_dtype", "float32") == "int8":
+                raise ValueError(
+                    f"{type(strategy).__name__} uplinks NATIVE int8 trees "
+                    f"whose per-tensor quantization grids assume each "
+                    f"parameter tensor is whole on every device; a 2-D "
+                    f"('{CLIENT_AXIS}', '{MODEL_AXIS}') mesh shards phi's "
+                    f"weight matrices — run int8 strategies on a 1-D "
+                    f"'{CLIENT_AXIS}' mesh (or mesh=None) instead")
+        elif partitioner is not None:
+            raise ValueError(
+                f"partitioner= only applies to a 2-D ('{CLIENT_AXIS}', "
+                f"'{MODEL_AXIS}') mesh (build one with "
+                f"repro.runtime.sharding.client_model_mesh); this run's mesh "
+                f"is {'1-D' if mesh is not None else 'None'} and phi stays "
+                f"replicated")
+        # a mesh spanning >1 process (jax.distributed) changes only HOW
+        # arrays move: every process runs this same host loop on the same
+        # seed (plans, rng draws, and bills are process-replicated), each
+        # contributes its addressable shard at staging, and device->host
+        # reads of client-sharded state go through a replicating collective
+        multiproc = (mesh is not None and
+                     len({d.process_index for d in mesh.devices.flat}) > 1)
+
+        def stage_tree(tree, target):
+            """device_put — or, cross-host, per-leaf global-array assembly
+            from the process-replicated host copy (device_put cannot build
+            an array it only partially addresses)."""
+            if not multiproc:
+                return jax.device_put(tree, target)
+            return jax.tree.map(
+                lambda x, s: jax.make_array_from_callback(
+                    np.shape(x), s, lambda idx, _x=np.asarray(x): _x[idx]),
+                tree, target)
+
+        def fetch_tree(tree):
+            """device_get — or, cross-host, an all-gather into replicated
+            form first (client-sharded leaves are not fully addressable
+            from any one process). The gather is a collective: every
+            process calls this at the same points, which the lockstep host
+            loop guarantees."""
+            if not multiproc:
+                return jax.device_get(tree)
+            rep = jax.jit(
+                lambda t: t,
+                out_shardings=jax.tree.map(
+                    lambda _: NamedSharding(mesh, P()), tree))(tree)
+            return jax.tree.map(np.asarray, rep)
+        # mesh runs pad the cohort to a multiple of the shard count: the
+        # pad slots are permanently scheduled out (participation False,
+        # weight 0, zero batch) so every device sees an equal shard and the
+        # validity-mask machinery keeps them inert
+        c_pad = -(-clients_per_round // shards) * shards
+        # pool-state LAYOUT: the 1-D manual shard_map body needs the
+        # per-shard layout (per-shard FedBuff slabs, (shards,) local fill
+        # levels); the 2-D GSPMD route runs the GLOBAL body, which sees the
+        # whole state like a flat run does — build the shards == 1 layout
+        # and let the committed input shardings split it
+        state_shards = 1 if model_sharded else shards
+        # residency="host" pools keep the (N,) identity arrays in host
+        # slabs; the device carries only a fixed gathered WINDOW of the
+        # rows each block actually touches (O(block cohort), not O(N)) —
+        # the producer remaps cohort indices window-local, the consumer
+        # stages the window before each block and scatters it back after
+        host_resident = pooled and pool.residency == "host"
+        slabs = pool.init_slabs(shards=state_shards) if host_resident else None
+        rng = np.random.default_rng(seed)
+        # private copy: the block runner donates its phi argument, and the
+        # caller's init_params must stay usable (they are reused across runs)
+        phi = jax.tree.map(jnp.array, init_params)
+        history: List[Dict] = []
+        comm_bytes = 0
+        start_round = 0
+        per_client_bytes = np.zeros(pool.size if pooled else clients_per_round,
+                                    np.int64)
+        uniform = getattr(sampling, "schedule_kind", "scheduled") == "uniform"
+        scheduled = pooled or mesh is not None or not uniform
+        # uniform schedules run every client at the full budget, so the
+        # scheduled body skips the per-step lax.cond masking (bit-for-bit
+        # identical at k == budget, without the per-inner-step overhead)
+        masked = scheduled and not uniform
+        budget = int(strategy.local_step_budget(support))
+        run_block = _block_runner(strategy, beta, channel, scheduled,
+                                  pooled=pooled, buffered=buffered, mesh=mesh,
+                                  masked=masked, partitioner=partitioner)
+        # FedBuff buffers stage whatever the strategy uplinks — sized from
+        # its template so quantized strategies buffer int8 trees at int8
+        # width, never dequantized copies
+        uplink_template = getattr(strategy, "uplink_template", None)
+        pool_state = (pool.init_state(
+            phi, c_pad, buffered, shards=state_shards,
+            template=uplink_template(phi) if uplink_template else None)
+            if pooled else None)
+        if ckpt_dir is not None:
+            if not (isinstance(ckpt_every, int) and ckpt_every >= 1):
+                raise ValueError(f"ckpt_every must be an int >= 1, got "
+                                 f"{ckpt_every!r}")
+            if not (isinstance(ckpt_keep, int) and ckpt_keep >= 1):
+                raise ValueError(f"ckpt_keep must be an int >= 1, got "
+                                 f"{ckpt_keep!r}")
+            # config identity stamped into every snapshot: a resume under a
+            # different seed/cohort/pool/mesh would replay a DIFFERENT run
+            # from this run's carry — reject it instead of training garbage
+            fingerprint = {
+                "seed": int(seed), "clients_per_round": int(clients_per_round),
+                "support": int(support), "shards": int(shards),
+                # full mesh topology + partitioning identity: a snapshot of
+                # model-sharded (or differently-mesh-shaped) phi must never
+                # silently resume into a run with a different layout
+                "mesh": (",".join(f"{a}:{int(mesh.shape[a])}"
+                                  for a in mesh.axis_names)
+                         if mesh is not None else ""),
+                "partitioner": partitioner.name if partitioner is not None
+                else "",
+                "strategy": type(strategy).__name__,
+                "pool_size": int(pool.size) if pooled else 0,
+                "pool_sampler": pool.sampler if pooled else "",
+                "policy_sampler": getattr(sampling, "sampler", "reference"),
+                "buffered": buffered is not None}
+        elif resume:
+            raise ValueError("resume=True needs ckpt_dir= to restore from")
+        if resume:
+            try:
+                saved = restore_round_state(
+                    ckpt_dir, phi=phi, pool_state=pool_state,
+                    per_client_bytes=per_client_bytes)
+            except FileNotFoundError:
+                logger.info("resume: no snapshot in %s yet; starting fresh",
+                            ckpt_dir)
+                saved = None
+            if saved is not None:
+                diff = {k: (saved.fingerprint.get(k), v)
+                        for k, v in fingerprint.items()
+                        if saved.fingerprint and saved.fingerprint.get(k) != v}
+                if diff:
+                    raise ValueError(
+                        f"checkpoint in {ckpt_dir} was written by a different "
+                        f"run config (saved != current): {diff}")
+                if saved.round > rounds:
+                    raise ValueError(
+                        f"checkpoint in {ckpt_dir} is at round {saved.round}, "
+                        f"past rounds={rounds}; raise the horizon to continue")
+                start_round = int(saved.round)
+                phi = jax.tree.map(jnp.asarray, saved.phi)
+                if pooled:
+                    pool_state = jax.tree.map(jnp.asarray, saved.pool_state)
+                    pool.load_host_state(saved.host.get("pool", {}))
+                per_client_bytes = np.asarray(saved.per_client_bytes,
+                                              np.int64).copy()
+                comm_bytes = int(saved.comm_bytes)
+                history = list(saved.history)
+                # the host rng resumes EXACTLY where the interrupted run's
+                # producer stopped drawing — the bit-for-bit contract
+                rng.bit_generator.state = saved.host["rng"]
+                sampling.load_state_dict(saved.host.get("sampling", {}),
+                                         rng=rng)
+                logger.info("resumed %s from round %d", ckpt_dir, start_round)
+        blocks, pad = plan_blocks(rounds, eval_every, max_block,
+                                  start=start_round,
+                                  ckpt_every=ckpt_every if ckpt_dir else 0)
+        run_span.set_metadata(blocks=len(blocks))
+        if host_resident:
+            # flush the full (possibly just-restored) identity into the
+            # host slabs, then shrink the device carry to the gathered
+            # window: one row per DISTINCT client a block can seat (a block
+            # has pad rounds of c_pad slots), fixed for the whole run so
+            # the runner still compiles once
+            n_full = len(slabs["last_seen"])
+            pool.scatter_rows(
+                np.arange(n_full),
+                {f: np.asarray(getattr(pool_state, f))
+                 for f in ClientPool.SLAB_FIELDS})
+            slab_rows = min(n_full,
+                            -(-pad * c_pad // state_shards) * state_shards)
+            win = pool.init_state(
+                phi, c_pad, buffered, shards=state_shards,
+                template=uplink_template(phi) if uplink_template else None,
+                rows=slab_rows)
+            # identity rows are re-staged from the slabs every block; the
+            # FedBuff buffer is SERVER state and carries over (restored
+            # buffers survive the shrink)
+            pool_state = PoolState(
+                win.last_seen, win.staleness, win.checkins,
+                pool_state.buf_updates, pool_state.buf_round,
+                pool_state.buf_count, pool_state.flushes)
+        if mesh is not None:
+            # 1-D mesh: phi fully replicated. 2-D mesh: each leaf carries
+            # the partitioner's NamedSharding — weight matrices split on
+            # the model axis, norms/biases replicated — and stays that way
+            # through the whole run (aggregation psums over the clients
+            # axis leave the model-axis shards in place; phi is never
+            # gathered whole onto one device)
+            phi = jax.device_put(
+                phi, partitioner.shardings(phi, mesh) if model_sharded
+                else NamedSharding(mesh, P()))
+        if mesh is not None and pooled:
+            # 1-D manual route: rows and FedBuff slabs are device_put in
+            # shard_map's layout. 2-D GSPMD route: the flat-layout state is
+            # staged replicated (rows are O(N) int32, not padded to the
+            # clients extent) and the compiler re-shards inside the block
+            # as the client-phase shardings dictate.
+            pool_state = stage_tree(
+                jax.tree.map(np.asarray, pool_state) if multiproc
+                else pool_state,
+                jax.tree.map(lambda s: NamedSharding(mesh, s),
+                             (jax.tree.map(lambda _: P(), pool_state)
+                              if model_sharded
+                              else pool_state_specs(pool_state, CLIENT_AXIS)),
+                             is_leaf=lambda x: isinstance(x, P)))
+
+        def ckpt_at(end):
+            """Deterministic snapshot predicate, shared by the producer's
+            host-state capture and the consumer's device-state snapshot
+            (plan_blocks cuts blocks at these rounds when ckpt_dir is set)."""
+            return ckpt_dir is not None and (end == rounds
+                                             or end % ckpt_every == 0)
+
+        def snapshot_host():
+            """Host-side carry at 'all draws for blocks <= i done' — called
+            on the prefetch producer right after block i's sampling, so a
+            resume continues the rng/pool/policy streams exactly where the
+            uninterrupted run's producer would."""
+            snap = {"rng": copy.deepcopy(rng.bit_generator.state)}
+            if pooled:
+                snap["pool"] = pool.host_state()
+            policy_state = sampling.state_dict()
+            if policy_state:
+                snap["sampling"] = policy_state
+            return snap
+
+        host_snaps: Dict[int, dict] = {}
+        writer = (AsyncCheckpointWriter(ckpt_dir, keep=ckpt_keep)
+                  if ckpt_dir is not None and ckpt_async and blocks else None)
+        device = single_device_of(phi)   # staging target for the prefetcher
+        if strategy.meters_comm:
+            # per-round payloads repeat with the channel's rotation period
+            # (period 1 = the constant legacy accounting)
+            period = (channel.rotation_period
+                      if getattr(channel, "rotate", False) else 1)
+            payload_by_phase = np.array(
+                [channel.payload_bytes_at(init_params, j)
+                 for j in range(period)], np.int64)
+
+        def stage(i):
+            """Plan the schedule, sample, pad, and device-stage block i.
+            Called strictly in block order (inline, or from the single
+            prefetch thread), so the host RNG stream is
+            prefetch-schedule-independent: plan_schedule (or its pooled
+            variant) draws first, then the data sampling, every block."""
+            start, end = blocks[i]
+            blk = end - start
+            with TraceAnnotation("engine.plan", block=i, rounds=blk):
+                if pooled:
+                    plan = sampling.plan_pool_schedule(
+                        rng, start, end, clients_per_round, budget,
+                        pool.size)
+                    cohort = np.asarray(plan["cohort"], np.int32)
+                else:
+                    plan = sampling.plan_schedule(rng, start, end,
+                                                  clients_per_round, budget)
+                    cohort = None
+                part = np.asarray(plan["participation"], bool)
+            with TraceAnnotation("engine.sample", block=i, rounds=blk):
+                if pooled:
+                    batch = pool.sample_cohort_block(cohort, part, support,
+                                                     strategy.data_mode)
+                else:
+                    batch = sampling.sample_block(task_dist, rng, blk,
+                                                  clients_per_round, support,
+                                                  strategy.data_mode,
+                                                  participation=part)
+            with TraceAnnotation("engine.stage", block=i,
+                                 rounds=blk) as span:
+                uniq, sched_cohort = None, cohort
+                if host_resident:
+                    # remap global cohort ids to window-local rows: the
+                    # sorted distinct participants seat the window
+                    # prefix, searchsorted inverts the map.
+                    # Non-participant slots clamp into range (they are
+                    # masked in-scan) and billing keeps the GLOBAL ids.
+                    uniq = np.unique(cohort[part]).astype(np.int64)
+                    if uniq.size:
+                        sched_cohort = np.searchsorted(
+                            uniq, cohort).astype(np.int32)
+                        np.clip(sched_cohort, 0, uniq.size - 1,
+                                out=sched_cohort)
+                    else:
+                        sched_cohort = np.zeros_like(cohort)
+                r = np.arange(start, end)
+                alphas = np.zeros(pad, np.float32)
+                alphas[:blk] = alpha * (1 - r / rounds) if anneal else alpha
+                valid = np.zeros(pad, bool)
+                # pooled rounds where nobody checked in (an availability
+                # trough) are runtime no-ops, same as the padding mask
+                valid[:blk] = part.any(axis=1) if pooled else True
+                round_index = np.zeros(pad, np.int32)
+                round_index[:blk] = r
+
+                def pad_rows(a, dtype):
+                    # pads BOTH axes: short tail blocks on the round axis
+                    # and the mesh cohort pad (c_pad == clients_per_round
+                    # off-mesh)
+                    out = np.zeros((pad, c_pad), dtype)
+                    out[:blk, :clients_per_round] = a
+                    return out
+
+                sched = ClientSchedule(
+                    valid=valid, alpha=alphas, round_index=round_index,
+                    participation=pad_rows(part, bool),
+                    local_steps=pad_rows(plan["local_steps"], np.int32),
+                    weights=pad_rows(plan["weights"], np.float32),
+                    cohort=(pad_rows(sched_cohort, np.int32) if pooled
+                            else None))
+                batch = {k: np.asarray(v) for k, v in batch.items()}
+                if c_pad > clients_per_round:
+                    batch = {k: np.concatenate(
+                        [v, np.zeros((v.shape[0], c_pad - clients_per_round)
+                                     + v.shape[2:], v.dtype)], axis=1)
+                        for k, v in batch.items()}
+                if blk < pad:
+                    batch = {k: np.concatenate(
+                        [v, np.zeros((pad - blk,) + v.shape[1:], v.dtype)])
+                        for k, v in batch.items()}
+                span.set_metadata(bytes=sum(
+                    x.nbytes for x in jax.tree.leaves((sched, batch))))
+                target = (block_shardings(mesh, CLIENT_AXIS, (sched, batch))
+                          if mesh is not None else device)
+                if ckpt_at(end):
+                    host_snaps[end] = snapshot_host()
+                return part, cohort, uniq, stage_tree((sched, batch),
+                                                      target)
+
+        id_sharding = (NamedSharding(mesh, P(CLIENT_AXIS))
+                       if mesh is not None else device)
+
+        def stage_window(uniq):
+            """Gather the block's identity rows from the host slabs onto
+            device (window prefix = the block's distinct participants, tail
+            rows inert). Runs on the CONSUMER, after the previous block's
+            write-back — the prefetch thread never races the slabs."""
+            uniq_pad = np.zeros(slab_rows, np.int64)
+            uniq_pad[:uniq.size] = uniq
+            rows = pool.gather_rows(uniq_pad)
+            rows = tuple(rows[f] for f in ClientPool.SLAB_FIELDS)
+            return stage_tree(rows, (None if id_sharding is None else
+                                     tuple(id_sharding for _ in rows)))
+
+        staged_iter = prefetch_items(stage, len(blocks), depth=prefetch)
+        try:
+            for i, (start, end) in enumerate(blocks):
+                blk = end - start
+                with TraceAnnotation("engine.wait", block=i, rounds=blk):
+                    part, cohort, uniq, (sched_d, batch_d) = next(
+                        staged_iter)
+                if host_resident:
+                    with TraceAnnotation("engine.stage", block=i,
+                                         rounds=blk):
+                        ls, st, ck = stage_window(uniq)
+                    pool_state = PoolState(
+                        ls, st, ck, pool_state.buf_updates,
+                        pool_state.buf_round, pool_state.buf_count,
+                        pool_state.flushes)
+                with TraceAnnotation("engine.dispatch", block=i, rounds=blk):
+                    if pooled:
+                        phi, pool_state, round_losses = run_block(
+                            phi, pool_state, sched_d, batch_d)
+                    else:
+                        phi, round_losses = run_block(phi, sched_d, batch_d)
+                with TraceAnnotation("engine.fetch", block=i, rounds=blk):
+                    if host_resident and uniq.size:
+                        got = fetch_tree(
+                            tuple(getattr(pool_state, f)
+                                  for f in ClientPool.SLAB_FIELDS))
+                        pool.scatter_rows(
+                            uniq, {f: np.asarray(g)[:uniq.size] for f, g in
+                                   zip(ClientPool.SLAB_FIELDS, got)})
+                    if tracker is not None:
+                        # the loss fetch syncs on the block — done ONLY
+                        # when a tracker asks for it, so tracker=None
+                        # stays fetch-free
+                        tracker.on_block(start, end,
+                                         np.asarray(round_losses)[:blk])
+                if strategy.meters_comm:
+                    # bill downlink + uplink per participating client, at the
+                    # round's exact (possibly rotating) payload
+                    payloads = payload_by_phase[
+                        np.arange(start, end) % len(payload_by_phase)]
+                    if pooled:
+                        # bill the POOL CLIENT seated in each participating
+                        # slot (np.add.at accumulates repeat check-ins)
+                        bills = 2 * payloads[:, None] * part
+                        np.add.at(per_client_bytes, cohort[part], bills[part])
+                    else:
+                        per_client_bytes += (
+                            2 * payloads[:, None] * part).sum(0)
+                    block_bytes = int((2 * payloads * part.sum(axis=1)).sum())
+                    comm_bytes += block_bytes
+                    if tracker is not None:
+                        tracker.on_transport(end, block_bytes, comm_bytes)
+                if eval_every and end % eval_every == 0:
+                    with TraceAnnotation("engine.eval", block=i, rounds=blk):
+                        # cross-host: run the eval protocol on a LOCAL
+                        # numpy copy of the replicated phi, so it stays a
+                        # per-process computation (identical on every
+                        # process) instead of a collective
+                        eval_phi = (jax.tree.map(np.asarray, phi)
+                                    if multiproc else phi)
+                        ev = evaluate_init(
+                            strategy.loss_fn, eval_phi, task_dist,
+                            np.random.default_rng(10_000 + end - 1),
+                            **(eval_kwargs or {}))
+                        ev["round"] = end
+                        if strategy.meters_comm:
+                            ev["comm_bytes"] = comm_bytes
+                        if strategy.tracks_inner_loss:
+                            ev["inner_loss"] = float(round_losses[blk - 1])
+                        history.append(ev)
+                        if tracker is not None:
+                            tracker.on_eval(ev)
+                if ckpt_at(end):
+                    with TraceAnnotation("engine.ckpt", block=i, rounds=blk):
+                        # block-boundary COPIES: the live carry is donated
+                        # to the next block, so the snapshot dispatches a
+                        # device copy (async, off the host critical path)
+                        # and hands THAT to the writer thread for the D2H
+                        # transfer cross-host snapshots materialize to host
+                        # numpy HERE (the replicating fetch is a collective
+                        # every process must join); single-process runs
+                        # keep the async device copy. Only process 0
+                        # touches the filesystem.
+                        snap_copy = fetch_tree if multiproc else _snapshot_copy
+                        if host_resident:
+                            # checkpoints always carry the FULL (N,)
+                            # layout — identity straight from the host
+                            # slabs (post write-back), buffer leaves
+                            # device-copied — so snapshots restore into
+                            # either residency
+                            pool_snap = PoolState(
+                                *(np.array(slabs[f])
+                                  for f in ClientPool.SLAB_FIELDS),
+                                *(snap_copy((
+                                    pool_state.buf_updates,
+                                    pool_state.buf_round,
+                                    pool_state.buf_count,
+                                    pool_state.flushes))))
+                        elif pooled:
+                            pool_snap = snap_copy(pool_state)
+                        else:
+                            pool_snap = None
+                        state = RoundState(
+                            round=end,
+                            phi=(jax.tree.map(np.asarray, phi) if multiproc
+                                 else _snapshot_copy(phi)),
+                            pool_state=pool_snap,
+                            per_client_bytes=per_client_bytes.copy(),
+                            comm_bytes=comm_bytes, history=list(history),
+                            host=host_snaps.pop(end),
+                            fingerprint=fingerprint)
+                        if multiproc and jax.process_index() != 0:
+                            pass                 # peers only joined the fetch
+                        elif writer is not None:
+                            writer.submit_state(state)
+                        else:
+                            save_round_state(ckpt_dir, state, keep=ckpt_keep)
+            if writer is not None:
+                writer.close()      # drain pending snapshots; surface errors
+        finally:
+            staged_iter.close()
+            if writer is not None:
+                writer.close(raise_errors=False)
+
+        out = {"params": phi, "history": history}
+        if strategy.meters_comm:
+            out["comm_bytes"] = comm_bytes
+            # C-level tolist(), not a per-element int() loop: the bill has
+            # pool.size entries, and a million-client fleet pays ~100ms for
+            # the boxing loop vs ~10ms here
+            out["per_client_bytes"] = per_client_bytes.tolist()
+        if pooled:
+            ps = fetch_tree(pool_state)
+            # [:pool.size] drops the mesh shard-padding rows (a no-op slice
+            # on unsharded runs); host-resident identity reads from the
+            # slabs (the device window only holds the last block's rows)
+            ident = (slabs if host_resident else
+                     {f: getattr(ps, f) for f in ClientPool.SLAB_FIELDS})
+            out["pool_state"] = {
+                f: np.array(ident[f][:pool.size])
+                for f in ClientPool.SLAB_FIELDS}
+            if buffered is not None:
+                out["pool_state"]["flushes"] = int(ps.flushes)
+                # scalar off-mesh; per-shard fill levels (shards,) on mesh
+                out["pool_state"]["buffered_pending"] = int(
+                    np.asarray(ps.buf_count).sum())
+        if tracker is not None:
+            tracker.on_run_end(
+                runner_cache_stats(),
+                staleness=(out["pool_state"]["staleness"] if pooled else None))
+        return out

@@ -8,15 +8,43 @@ a tracker never changes what the device computes — ``run_federated``
 with ``tracker=None`` and with a tracker produce bit-for-bit identical
 params/history (pinned in tests/test_metrics.py).
 
-Closes ROADMAP item 2's leftover: the per-round metrics tracker
-(losses, transport bills, staleness histograms, trace/cache counters)
-and the JAX-profiler hook (``profile_dir=`` brackets the run in
-``jax.profiler.start_trace``/``stop_trace``).
+Timings live in the profiler's trace, not here. The engine and the
+server open ``jax.profiler.TraceAnnotation`` spans, which a running
+profiler session (``jax.profiler.trace(dir)``) records on the device
+trace's clock; with no session they cost about a microsecond each.
+Counts ride on the spans as arguments. Nothing opens a span per client,
+sample or request.
+
+- ``engine.run``: one ``run_federated`` call; ``rounds``, ``clients``,
+  ``seed``, ``blocks``.
+- Per block, each with ``block`` (its index in the call) and ``rounds``
+  (the block's rounds), which pair the prefetch thread's spans with the
+  consumer's:
+  ``engine.plan`` (the sampling policy's schedule), ``engine.sample``
+  (its client data), ``engine.stage`` (padding, the ``ClientSchedule``
+  and the device transfer; ``bytes`` staged; a host-resident pool's
+  identity window is a second one, on the consumer), ``engine.wait``
+  (the consumer waiting for the block; with no prefetch thread it holds
+  the block's plan, sample and stage), ``engine.dispatch`` (the block
+  runner call), ``engine.fetch`` (the tracker's loss fetch and the
+  host-resident pool's write-back), ``engine.eval``, ``engine.ckpt``.
+- ``serve.tick``: one ``AdaptationServer.step`` that runs a tick;
+  ``tick`` (its index), ``admitted``, ``retired``, ``live`` (the
+  slot-steps that do real work: per slot in flight, the least of the
+  tick's steps and the steps it has left) and ``computed`` (slots x
+  steps per tick). Its leaves: ``serve.refill`` (admission;
+  ``rid_lo``, ``rid_hi``: the half-open range of the request ids it
+  admits), ``serve.dispatch`` (the jitted tick), ``serve.readback``
+  (the reads of ``finished``, the query losses and the step counts) and
+  ``serve.retire``.
+
+The device programs name their parts with ``jax.named_scope``: the
+block runner's ``client_phase`` and ``server_update``, the tick's
+``refill_scatter``, ``advance`` and ``query``.
 """
 from __future__ import annotations
 
 import collections
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -32,22 +60,17 @@ class MetricsTracker:
     - ``record(name, step, v)`` per-step series (round -> loss)
     - ``observe(name, v)``    distribution sample (latencies, steps)
 
-    ``percentiles``/``histogram`` summarize observations; ``summary()``
-    returns one JSON-able dict of everything. ``profile_dir=`` arms the
-    JAX profiler: ``start_profile()``/``stop_profile()`` bracket a
-    region (the engine calls them around the scan loop when the tracker
-    is attached).
+    ``percentiles`` summarizes observations; ``summary()`` returns one
+    JSON-able dict of everything.
     """
 
-    def __init__(self, profile_dir: Optional[str] = None):
-        self.profile_dir = profile_dir
+    def __init__(self):
         self.counters: Dict[str, float] = collections.defaultdict(float)
         self.gauges: Dict[str, float] = {}
         self.series: Dict[str, List[Tuple[int, float]]] = (
             collections.defaultdict(list))
         self.observations: Dict[str, List[float]] = (
             collections.defaultdict(list))
-        self._profiling = False
 
     # -- primitives --------------------------------------------------------
     def inc(self, name: str, value: float = 1.0) -> None:
@@ -73,14 +96,6 @@ class MetricsTracker:
         pct = np.percentile(np.asarray(vals, np.float64), qs)
         return {f"p{q:g}": float(p) for q, p in zip(qs, pct)}
 
-    def histogram(self, name: str, bins: int = 10) -> Dict:
-        vals = self.observations.get(name)
-        if not vals:
-            return {"counts": [], "edges": []}
-        counts, edges = np.histogram(np.asarray(vals, np.float64),
-                                     bins=bins)
-        return {"counts": counts.tolist(), "edges": edges.tolist()}
-
     def series_values(self, name: str) -> List[float]:
         return [v for _, v in self.series.get(name, [])]
 
@@ -95,29 +110,10 @@ class MetricsTracker:
                 **self.percentiles(name)}
         return out
 
-    # -- JAX profiler hook -------------------------------------------------
-    def start_profile(self) -> None:
-        if self.profile_dir is None or self._profiling:
-            return
-        import jax
-        jax.profiler.start_trace(self.profile_dir)
-        self._profiling = True
-
-    def stop_profile(self) -> None:
-        if not self._profiling:
-            return
-        import jax
-        jax.profiler.stop_trace()
-        self._profiling = False
-
     # -- engine hooks (run_federated) --------------------------------------
     # All hooks receive host values the engine already has (or fetches
     # only when a tracker is attached); none of them feed anything back,
     # so the training trajectory is tracker-independent by construction.
-    def on_run_start(self) -> None:
-        self._run_t0 = time.perf_counter()
-        self.start_profile()
-
     def on_block(self, start: int, end: int, losses) -> None:
         """Per-round inner losses of one executed scan block
         (``losses[i]`` is round ``start + i``'s cohort-weighted loss)."""
@@ -139,10 +135,6 @@ class MetricsTracker:
 
     def on_run_end(self, runner_stats: Optional[Dict] = None,
                    staleness=None) -> None:
-        self.stop_profile()
-        self.gauge("engine.wall_s",
-                   time.perf_counter() - getattr(self, "_run_t0",
-                                                 time.perf_counter()))
         if runner_stats:
             for k, v in runner_stats.items():
                 self.gauge(f"runner_cache.{k}", float(v))

@@ -32,6 +32,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -108,6 +109,9 @@ class AdaptationServer:
         self._queue: collections.deque = collections.deque()
         self._inflight: Dict[int, _Pending] = {}
         self._free = list(range(self.B))      # ascending slot ids
+        # adaptation steps each slot has still to run: the host's exact
+        # count of the slot-steps a tick does real work on
+        self._left = np.zeros(self.B, np.int64)
         self._next_rid = 0
         self._state = None                    # allocated on first submit
         self._shapes = None
@@ -119,36 +123,39 @@ class AdaptationServer:
         B = self.B
         ad = self.adapter
         idx = refill["idx"]                   # (B,) int32; idx == B drops
-        fresh = jax.vmap(lambda sx, sy: ad.prepare(pack, sx, sy))(
-            refill["sx"], refill["sy"])
-        slots = jax.tree.map(
-            lambda s, f: s.at[idx].set(f, mode="drop"),
-            state["slots"], fresh)
-        qx = state["qx"].at[idx].set(refill["qx"], mode="drop")
-        qy = state["qy"].at[idx].set(refill["qy"], mode="drop")
-        k = state["k"].at[idx].set(refill["k"], mode="drop")
-        step = state["step"].at[idx].set(0, mode="drop")
-        active = state["active"].at[idx].set(True, mode="drop")
-        qloss = state["qloss"].at[idx].set(0.0, mode="drop")
-
-        unit = jax.vmap(lambda s, t: ad.unit_step(pack, s, t))
-        for _ in range(self.steps_per_tick):
-            live = active & (step < k)
-            new_slots, _ = unit(slots, step)
+        with jax.named_scope("refill_scatter"):
+            fresh = jax.vmap(lambda sx, sy: ad.prepare(pack, sx, sy))(
+                refill["sx"], refill["sy"])
             slots = jax.tree.map(
-                lambda n, o: jnp.where(_bcast(live, n), n, o),
-                new_slots, slots)
-            step = step + live.astype(jnp.int32)
+                lambda s, f: s.at[idx].set(f, mode="drop"),
+                state["slots"], fresh)
+            qx = state["qx"].at[idx].set(refill["qx"], mode="drop")
+            qy = state["qy"].at[idx].set(refill["qy"], mode="drop")
+            k = state["k"].at[idx].set(refill["k"], mode="drop")
+            step = state["step"].at[idx].set(0, mode="drop")
+            active = state["active"].at[idx].set(True, mode="drop")
+            qloss = state["qloss"].at[idx].set(0.0, mode="drop")
 
-        finished = active & (step >= k)
-        ql = jax.vmap(lambda s, x, y: ad.query_loss(pack, s, x, y))(
-            slots, qx, qy)
-        qloss = jnp.where(finished, ql, qloss)
-        active = active & ~finished
+        with jax.named_scope("advance"):
+            unit = jax.vmap(lambda s, t: ad.unit_step(pack, s, t))
+            for _ in range(self.steps_per_tick):
+                live = active & (step < k)
+                new_slots, _ = unit(slots, step)
+                slots = jax.tree.map(
+                    lambda n, o: jnp.where(_bcast(live, n), n, o),
+                    new_slots, slots)
+                step = step + live.astype(jnp.int32)
+
+        with jax.named_scope("query"):
+            finished = active & (step >= k)
+            ql = jax.vmap(lambda s, x, y: ad.query_loss(pack, s, x, y))(
+                slots, qx, qy)
+            qloss = jnp.where(finished, ql, qloss)
+            active = active & ~finished
+            params = (jax.vmap(lambda s: ad.finish(pack, s))(slots)
+                      if self.return_params else ())
         new_state = {"slots": slots, "qx": qx, "qy": qy, "k": k,
                      "step": step, "active": active, "qloss": qloss}
-        params = (jax.vmap(lambda s: ad.finish(pack, s))(slots)
-                  if self.return_params else ())
         return new_state, finished, qloss, step, params
 
     def _alloc_state(self, req: _Pending):
@@ -201,6 +208,8 @@ class AdaptationServer:
         return rid
 
     def _build_refill(self):
+        """The tick's refill arrays and how many queued requests it
+        admits (the queue's head first)."""
         B = self.B
         sh = self._shapes
         refill = {
@@ -221,44 +230,61 @@ class AdaptationServer:
             refill["qx"][n] = req.qx
             refill["qy"][n] = req.qy
             refill["k"][n] = req.k
+            self._left[slot] = req.k
             self._inflight[slot] = req
             n += 1
-        return refill
+        return refill, n
 
     def step(self) -> List[AdaptResult]:
         """Admit waiting requests into free slots, run ONE tick, retire
         finished slots. Returns this tick's retired results."""
         if not self._queue and not self._inflight:
             return []
-        if self._state is None:
-            self._alloc_state(self._queue[0])
-        refill = self._build_refill()
-        self._state, finished, qloss, step, params = self._jit_tick(
-            self._pack, self._state, refill)
-        self.ticks += 1
-        if self.metrics is not None:
-            self.metrics.on_tick()
-        fin = np.asarray(finished)
-        results: List[AdaptResult] = []
-        if fin.any():
-            ql = np.asarray(qloss)
-            st = np.asarray(step)
-            now = time.monotonic()
-            for slot in np.nonzero(fin)[0]:
-                slot = int(slot)
-                req = self._inflight.pop(slot)
-                self._free.append(slot)
-                p = None
-                if self.return_params:
-                    p = jax.tree.map(lambda a: np.asarray(a[slot]),
-                                     params)
-                res = AdaptResult(rid=req.rid, query_loss=float(ql[slot]),
-                                  steps=int(st[slot]),
-                                  latency_s=now - req.t_submit, params=p)
-                results.append(res)
-                if self.metrics is not None:
-                    self.metrics.on_retire(res.latency_s, res.steps)
-            self._free.sort()
+        with TraceAnnotation("serve.tick", tick=self.ticks,
+                             computed=self.B * self.steps_per_tick) as span:
+            if self._state is None:
+                self._alloc_state(self._queue[0])
+            # admission is FIFO, so a tick admits consecutive ids
+            rid_lo = self._queue[0].rid if self._queue else self._next_rid
+            with TraceAnnotation("serve.refill") as refill_span:
+                refill, admitted = self._build_refill()
+                refill_span.set_metadata(rid_lo=rid_lo,
+                                         rid_hi=rid_lo + admitted)
+            live = np.minimum(self._left, self.steps_per_tick)
+            self._left -= live
+            with TraceAnnotation("serve.dispatch"):
+                self._state, finished, qloss, step, params = self._jit_tick(
+                    self._pack, self._state, refill)
+            self.ticks += 1
+            if self.metrics is not None:
+                self.metrics.on_tick()
+            with TraceAnnotation("serve.readback"):
+                done = np.flatnonzero(np.asarray(finished))
+                if done.size:
+                    ql = np.asarray(qloss)
+                    st = np.asarray(step)
+            results: List[AdaptResult] = []
+            with TraceAnnotation("serve.retire"):
+                now = time.monotonic()
+                for slot in done.tolist():
+                    req = self._inflight.pop(slot)
+                    self._free.append(slot)
+                    p = None
+                    if self.return_params:
+                        p = jax.tree.map(lambda a: np.asarray(a[slot]),
+                                         params)
+                    res = AdaptResult(rid=req.rid,
+                                      query_loss=float(ql[slot]),
+                                      steps=int(st[slot]),
+                                      latency_s=now - req.t_submit,
+                                      params=p)
+                    results.append(res)
+                    if self.metrics is not None:
+                        self.metrics.on_retire(res.latency_s, res.steps)
+                if done.size:
+                    self._free.sort()
+            span.set_metadata(admitted=admitted, retired=len(results),
+                              live=int(live.sum()))
         return results
 
     def drain(self) -> List[AdaptResult]:
@@ -286,6 +312,7 @@ class AdaptationServer:
         self._queue.clear()
         self._inflight.clear()
         self._free = list(range(self.B))
+        self._left[:] = 0
         self.ticks = 0
         if self._state is not None:
             self._state = jax.tree.map(

@@ -10,12 +10,10 @@ Contracts:
   the run's own outputs — per-round inner-loss series vs history rows,
   transport counters vs comm_bytes, staleness observations vs
   pool_state, eval series vs history.
-- The summary math (percentiles / histogram) matches NumPy.
-- ``profile_dir=`` really arms the JAX profiler (trace files appear).
+- The summary math (percentiles) matches NumPy.
 - The serving hooks agree with the AdaptationServer's own ledger.
 """
 import functools
-import glob
 
 import jax
 import numpy as np
@@ -104,7 +102,6 @@ def test_staleness_observations_match_pool_state(tracked):
 
 def test_run_end_gauges(tracked):
     _, tr = tracked
-    assert tr.gauges["engine.wall_s"] > 0
     assert any(k.startswith("runner_cache.") for k in tr.gauges)
 
 
@@ -117,24 +114,8 @@ def test_percentiles_match_numpy():
     want = np.percentile(vals, [50.0, 95.0, 99.0])
     assert got == {"p50": want[0], "p95": want[1], "p99": want[2]}
     assert tr.percentiles("missing") == {}
-    hist = tr.histogram("x", bins=7)
-    counts, edges = np.histogram(vals, bins=7)
-    assert hist == {"counts": counts.tolist(), "edges": edges.tolist()}
     summ = tr.summary()
     assert summ["distributions"]["x"]["count"] == 257
-
-
-def test_profile_dir_writes_trace(tmp_path):
-    """profile_dir= brackets the region in the JAX profiler and leaves
-    trace artifacts on disk."""
-    import jax.numpy as jnp
-    tr = MetricsTracker(profile_dir=str(tmp_path))
-    tr.on_run_start()
-    jnp.dot(jnp.ones((32, 32)), jnp.ones((32, 32))).block_until_ready()
-    tr.on_run_end()
-    files = glob.glob(str(tmp_path / "**" / "*"), recursive=True)
-    assert any(f.endswith(".xplane.pb") for f in files), files
-    tr.stop_profile()                               # idempotent no-op
 
 
 def test_serving_hooks_match_server_ledger():
