@@ -34,9 +34,10 @@ sample or request.
   tick's steps and the steps it has left) and ``computed`` (slots x
   steps per tick). Its leaves: ``serve.refill`` (admission;
   ``rid_lo``, ``rid_hi``: the half-open range of the request ids it
-  admits), ``serve.dispatch`` (the jitted tick), ``serve.readback``
-  (the reads of ``finished``, the query losses and the step counts) and
-  ``serve.retire``.
+  admits), ``serve.dispatch`` (the refill's one transfer and the jitted
+  tick; ``bytes``: the packed refill's size), ``serve.readback`` (the
+  one read of the packed ``finished``, step counts and query losses;
+  ``bytes``: its size) and ``serve.retire``.
 
 The device programs name their parts with ``jax.named_scope``: the
 block runner's ``client_phase`` and ``server_update``, the tick's

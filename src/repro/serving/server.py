@@ -32,7 +32,13 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 from jax.profiler import TraceAnnotation
+
+#: a request's float32 fields, in the order a refill row holds them
+_FIELDS = ("sx", "sy", "qx", "qy")
+#: a refill row's leading int32 columns: the slot it fills and its k
+_HEAD = 2
 
 
 @dataclasses.dataclass
@@ -50,11 +56,13 @@ class AdaptResult:
 
 
 class _Pending:
-    __slots__ = ("rid", "sx", "sy", "qx", "qy", "k", "t_submit")
+    """A queued request: ``row`` is its refill row, packed at submit
+    (int32: the slot, filled at admission, then k, then the float32 bits
+    of sx | sy | qx | qy flattened)."""
+    __slots__ = ("rid", "k", "row", "t_submit")
 
-    def __init__(self, rid, sx, sy, qx, qy, k, t_submit):
-        self.rid, self.sx, self.sy = rid, sx, sy
-        self.qx, self.qy, self.k = qx, qy, k
+    def __init__(self, rid, k, row, t_submit):
+        self.rid, self.k, self.row = rid, k, row
         self.t_submit = t_submit
 
 
@@ -83,8 +91,10 @@ class AdaptationServer:
         server.submit(sx, sy, qx, qy, k=7)
         results = server.drain()       # list of AdaptResult
 
-    Request/query shapes are fixed by the FIRST submitted request (the
-    padded-slot state is allocated then); later requests must match.
+    Request/query shapes are fixed by the FIRST submitted request; later
+    requests must match. Each tick moves one packed array each way: the
+    admitted requests' rows in, and ``finished``, the step counts and the
+    query losses out.
     """
 
     def __init__(self, phi, adapter, *, slots: int, k_max: int,
@@ -113,8 +123,9 @@ class AdaptationServer:
         # count of the slot-steps a tick does real work on
         self._left = np.zeros(self.B, np.int64)
         self._next_rid = 0
-        self._state = None                    # allocated on first submit
-        self._shapes = None
+        self._state = None                    # allocated on first step
+        self._shapes = None                   # fixed by the first submit
+        self._width = None                    # int32 columns of a row
         self._jit_tick = jax.jit(self._tick_fn, donate_argnums=(1,))
 
     # -- device program ----------------------------------------------------
@@ -122,16 +133,23 @@ class AdaptationServer:
         self.trace_count += 1                 # runs at trace time only
         B = self.B
         ad = self.adapter
-        idx = refill["idx"]                   # (B,) int32; idx == B drops
+        idx = refill[:, 0]                    # (B,) int32; idx == B drops
         with jax.named_scope("refill_scatter"):
+            data = lax.bitcast_convert_type(refill[:, _HEAD:], jnp.float32)
+            fields, lo = {}, 0
+            for name in _FIELDS:
+                shape = self._shapes[name]
+                hi = lo + int(np.prod(shape))
+                fields[name] = data[:, lo:hi].reshape((B,) + shape)
+                lo = hi
             fresh = jax.vmap(lambda sx, sy: ad.prepare(pack, sx, sy))(
-                refill["sx"], refill["sy"])
+                fields["sx"], fields["sy"])
             slots = jax.tree.map(
                 lambda s, f: s.at[idx].set(f, mode="drop"),
                 state["slots"], fresh)
-            qx = state["qx"].at[idx].set(refill["qx"], mode="drop")
-            qy = state["qy"].at[idx].set(refill["qy"], mode="drop")
-            k = state["k"].at[idx].set(refill["k"], mode="drop")
+            qx = state["qx"].at[idx].set(fields["qx"], mode="drop")
+            qy = state["qy"].at[idx].set(fields["qy"], mode="drop")
+            k = state["k"].at[idx].set(refill[:, 1], mode="drop")
             step = state["step"].at[idx].set(0, mode="drop")
             active = state["active"].at[idx].set(True, mode="drop")
             qloss = state["qloss"].at[idx].set(0.0, mode="drop")
@@ -154,24 +172,27 @@ class AdaptationServer:
             active = active & ~finished
             params = (jax.vmap(lambda s: ad.finish(pack, s))(slots)
                       if self.return_params else ())
+            # one (3, B) int32 read for the host: finished, step, and the
+            # query losses' float32 bits
+            out = jnp.stack([finished.astype(jnp.int32), step,
+                             lax.bitcast_convert_type(qloss, jnp.int32)])
         new_state = {"slots": slots, "qx": qx, "qy": qy, "k": k,
                      "step": step, "active": active, "qloss": qloss}
-        return new_state, finished, qloss, step, params
+        return new_state, out, params
 
-    def _alloc_state(self, req: _Pending):
-        self._shapes = {"sx": req.sx.shape, "sy": req.sy.shape,
-                        "qx": req.qx.shape, "qy": req.qy.shape}
+    def _alloc_state(self):
         B = self.B
-        sx0 = jnp.zeros((B,) + req.sx.shape, jnp.float32)
-        sy0 = jnp.zeros((B,) + req.sy.shape, jnp.float32)
+        sh = self._shapes
+        sx0 = jnp.zeros((B,) + sh["sx"], jnp.float32)
+        sy0 = jnp.zeros((B,) + sh["sy"], jnp.float32)
         slot_shapes = jax.eval_shape(
             jax.vmap(lambda sx, sy: self.adapter.prepare(
                 self._pack, sx, sy)), sx0, sy0)
         self._state = {
             "slots": jax.tree.map(
                 lambda a: jnp.zeros(a.shape, a.dtype), slot_shapes),
-            "qx": jnp.zeros((B,) + req.qx.shape, jnp.float32),
-            "qy": jnp.zeros((B,) + req.qy.shape, jnp.float32),
+            "qx": jnp.zeros((B,) + sh["qx"], jnp.float32),
+            "qy": jnp.zeros((B,) + sh["qy"], jnp.float32),
             "k": jnp.zeros((B,), jnp.int32),
             "step": jnp.zeros((B,), jnp.int32),
             "active": jnp.zeros((B,), bool),
@@ -191,45 +212,41 @@ class AdaptationServer:
         if k > sx.shape[0] and self.adapter.name == "fp32":
             raise ValueError(f"k={k} online steps need >= k support "
                              f"samples, got {sx.shape[0]}")
-        if self._shapes is not None:
-            for name, arr in (("sx", sx), ("sy", sy), ("qx", qx),
-                              ("qy", qy)):
-                if arr.shape != self._shapes[name]:
-                    raise ValueError(
-                        f"{name} shape {arr.shape} != server shape "
-                        f"{self._shapes[name]} (fixed by first request)")
+        arrays = (sx, sy, qx, qy)
+        if self._shapes is None:
+            self._shapes = {name: arr.shape
+                            for name, arr in zip(_FIELDS, arrays)}
+            self._width = _HEAD + sum(a.size for a in arrays)
+        for name, arr in zip(_FIELDS, arrays):
+            if arr.shape != self._shapes[name]:
+                raise ValueError(
+                    f"{name} shape {arr.shape} != server shape "
+                    f"{self._shapes[name]} (fixed by first request)")
+        row = np.empty(self._width, np.int32)
+        row[1] = k
+        np.concatenate([a.ravel() for a in arrays],
+                       out=row[_HEAD:].view(np.float32))
         rid = self._next_rid
         self._next_rid += 1
-        req = _Pending(rid, sx, sy, qx, qy, k, time.monotonic())
-        self._queue.append(req)
+        self._queue.append(_Pending(rid, k, row, time.monotonic()))
         if self.metrics is not None:
             self.metrics.on_admit(
                 sx.nbytes + sy.nbytes + qx.nbytes + qy.nbytes)
         return rid
 
     def _build_refill(self):
-        """The tick's refill arrays and how many queued requests it
-        admits (the queue's head first)."""
+        """The tick's refill, one (B, W) int32 host array with a row per
+        admitted request (the queue's head first) and rows that fill slot
+        B, which the tick drops, after them; and the admitted count."""
         B = self.B
-        sh = self._shapes
-        refill = {
-            "idx": np.full((B,), B, np.int32),
-            "sx": np.zeros((B,) + sh["sx"], np.float32),
-            "sy": np.zeros((B,) + sh["sy"], np.float32),
-            "qx": np.zeros((B,) + sh["qx"], np.float32),
-            "qy": np.zeros((B,) + sh["qy"], np.float32),
-            "k": np.zeros((B,), np.int32),
-        }
+        refill = np.zeros((B, self._width), np.int32)
+        refill[:, 0] = B
         n = 0
         while self._queue and self._free:
             req = self._queue.popleft()
             slot = self._free.pop(0)          # lowest free slot first
-            refill["idx"][n] = slot
-            refill["sx"][n] = req.sx
-            refill["sy"][n] = req.sy
-            refill["qx"][n] = req.qx
-            refill["qy"][n] = req.qy
-            refill["k"][n] = req.k
+            refill[n] = req.row
+            refill[n, 0] = slot
             self._left[slot] = req.k
             self._inflight[slot] = req
             n += 1
@@ -243,7 +260,7 @@ class AdaptationServer:
         with TraceAnnotation("serve.tick", tick=self.ticks,
                              computed=self.B * self.steps_per_tick) as span:
             if self._state is None:
-                self._alloc_state(self._queue[0])
+                self._alloc_state()
             # admission is FIFO, so a tick admits consecutive ids
             rid_lo = self._queue[0].rid if self._queue else self._next_rid
             with TraceAnnotation("serve.refill") as refill_span:
@@ -252,17 +269,19 @@ class AdaptationServer:
                                          rid_hi=rid_lo + admitted)
             live = np.minimum(self._left, self.steps_per_tick)
             self._left -= live
-            with TraceAnnotation("serve.dispatch"):
-                self._state, finished, qloss, step, params = self._jit_tick(
-                    self._pack, self._state, refill)
+            # one explicit transfer each way; the refill is fresh each
+            # tick, so the runtime may alias it without a copy
+            with TraceAnnotation("serve.dispatch", bytes=refill.nbytes):
+                self._state, out, params = self._jit_tick(
+                    self._pack, self._state, jax.device_put(refill))
+                out.copy_to_host_async()
             self.ticks += 1
             if self.metrics is not None:
                 self.metrics.on_tick()
-            with TraceAnnotation("serve.readback"):
-                done = np.flatnonzero(np.asarray(finished))
-                if done.size:
-                    ql = np.asarray(qloss)
-                    st = np.asarray(step)
+            with TraceAnnotation("serve.readback", bytes=out.nbytes):
+                out = np.asarray(out)
+                done = np.flatnonzero(out[0])
+                st, ql = out[1], out[2].view(np.float32)
             results: List[AdaptResult] = []
             with TraceAnnotation("serve.retire"):
                 now = time.monotonic()

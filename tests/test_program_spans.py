@@ -7,7 +7,8 @@ profiler session records them.
 - The server's ``serve.tick`` counts add up to the stream: admitted and
   retired to the requests, ``live`` to the steps they asked for, and the
   admitted id ranges follow one another; each tick holds its four
-  leaves.
+  leaves, and its dispatch and readback each name the bytes of the one
+  packed array they move.
 - Tracing changes nothing the program computes.
 """
 import functools
@@ -135,6 +136,23 @@ def test_serve_tick_counts_add_up(tmp_path):
     assert not server._left.any()
 
 
+def test_serve_tick_moves_one_packed_array_each_way(tmp_path):
+    """4 slots of requests with 6 support and 4 query samples of one
+    feature: a refill row is the slot, k and 6 + 6 + 4 + 4 float32
+    values, all 4-byte words; the result is finished, step and query
+    loss for each slot."""
+    _serve()                                   # compiled outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        server, _, _ = _serve()
+    spans = _spans(tmp_path)
+    slots, row_words = 4, 2 + 6 + 6 + 4 + 4
+    for name, nbytes in (("serve.dispatch", 4 * slots * row_words),
+                         ("serve.readback", 4 * 3 * slots)):
+        found = [s["args"] for s in spans if s["name"] == name]
+        assert len(found) == server.ticks
+        assert all(args == {"bytes": nbytes} for args in found), found
+
+
 @pytest.mark.parametrize("side", ["engine", "server"])
 def test_tracing_changes_nothing_computed(tmp_path, side):
     def once():
@@ -175,7 +193,7 @@ def test_device_scopes_reach_the_lowered_programs(side):
                                   k_max=5, steps_per_tick=2)
         x = np.zeros((6, 1), np.float32)
         server.submit(x, x, x[:4], x[:4], 2)
-        server._alloc_state(server._queue[0])
+        server._alloc_state()
         refill, _ = server._build_refill()
         lowered = jax.jit(server._tick_fn).lower(server._pack,
                                                  server._state, refill)

@@ -17,7 +17,11 @@ Contracts pinned here:
   config, across refills, resets, and phi swaps.
 - a `checkpoint.load_params` phi (from a training checkpoint) serves
   bit-for-bit identically to the in-memory phi.
+- a tick moves one packed array each way: the refill reaches the device
+  by one explicit transfer, and the query losses come back through the
+  int32 result with every bit (NaN, infinities, -0.0) kept.
 """
+import dataclasses
 import functools
 
 import jax
@@ -228,6 +232,67 @@ def test_tifed_no_cross_slot_leakage(phi):
     assert solo.query_loss == got.query_loss
     for leaf in solo.params:
         np.testing.assert_array_equal(solo.params[leaf], got.params[leaf])
+
+
+# -- the tick's packed transfers -------------------------------------------
+
+def test_tick_makes_no_implicit_host_to_device_transfer(phi):
+    """A warmed tick hands the jitted program no host array: its refill
+    goes to the device by one explicit `jax.device_put`."""
+    adapter = Fp32Adapter(loss_fn=LOSS, lr=0.01)
+    server = AdaptationServer(phi, adapter, slots=4, k_max=5,
+                              steps_per_tick=2)
+    reqs = make_requests(10, support=6, query=4, ks=(5, 1, 3, 2, 4))
+    serve_all(server, reqs[:4])                 # compiles the tick
+    for r in reqs[4:]:
+        server.submit(r["sx"], r["sy"], r["qx"], r["qy"], r["k"])
+    with jax.transfer_guard_host_to_device("disallow"):
+        results = server.drain()
+    assert sorted(res.rid for res in results) == list(range(4, 10))
+    assert server.trace_count == 1
+
+
+def _planted(adapter):
+    """``adapter`` with a request's query loss set to its ``qy[0, 0]``,
+    so a test can plant any float32 bit pattern there."""
+    class Planted(type(adapter)):
+        def query_loss(self, pack, slot, qx, qy):
+            return qy[0, 0]
+    return Planted(**{f.name: getattr(adapter, f.name)
+                      for f in dataclasses.fields(adapter)})
+
+
+@pytest.mark.parametrize("route", ["fp32", "int8"])
+def test_packed_readback_keeps_query_loss_bits(phi, route):
+    """NaNs (a payload and a sign included), infinities and -0.0 come
+    back bit for bit through the packed int32 result, and the step
+    counts with them, equal to the offline reference's."""
+    if route == "fp32":
+        served_phi = phi
+        adapter = _planted(Fp32Adapter(loss_fn=LOSS, lr=0.01))
+    else:
+        served_phi = tifed_requantize(phi)
+        adapter = _planted(TifedAdapter(support=8, k_max=6,
+                                        use_pallas=False))
+    bits = np.array([0x7FC00000, 0x7FC01234, 0xFFC00001,     # NaNs
+                     0x7F800000, 0xFF800000,                 # +inf, -inf
+                     0x80000000, 0x00000000,                 # -0.0, 0.0
+                     0x3FC00000, 0xC0100000],                # 1.5, -2.25
+                    np.uint32).view(np.int32)
+    planted = bits.view(np.float32)
+    reqs = make_requests(len(bits), support=8, query=4,
+                         ks=(6, 1, 3, 5, 2, 4), seed=4)
+    for r, v in zip(reqs, planted):
+        r["qy"][0, 0] = v
+    server = AdaptationServer(served_phi, adapter, slots=4, k_max=6,
+                              steps_per_tick=2)
+    results = serve_all(server, reqs)
+    offline = offline_adapt(served_phi, adapter, reqs, slots=4, k_max=6)
+    for i, r in enumerate(reqs):
+        got = np.float32(results[i].query_loss).view(np.int32)
+        want = np.float32(offline[i]["query_loss"]).view(np.int32)
+        assert got == want == bits[i], (i, hex(got), hex(want))
+        assert results[i].steps == offline[i]["steps"] == r["k"]
 
 
 # -- request validation -----------------------------------------------------
