@@ -16,6 +16,10 @@ ATTN = "attn"          # attention + MLP block (dense)
 MOE = "moe"            # attention + MoE block
 MAMBA = "mamba"        # Mamba2 (SSD) block
 SHARED_ATTN = "shared_attn"  # weight-shared full transformer block (zamba2)
+MAMBA_MLP = "mamba_mlp"  # Mamba2 mixer + MLP block (granite-4.0-h)
+
+#: ``layer_types`` entries (a published config's own names) -> block kinds
+LAYER_TYPES = {"mamba": MAMBA_MLP, "attention": ATTN}
 
 
 @dataclass(frozen=True)
@@ -46,6 +50,9 @@ class ArchConfig:
 
     # --- hybrid (zamba2): shared transformer block every n mamba blocks ---
     hybrid_attn_every: int = 0
+    # --- hybrid from a published per-layer list (granite-4.0-h): each
+    #     entry of LAYER_TYPES, the first num_layers of them used ---
+    layer_types: Tuple[str, ...] = ()
 
     # --- attention variants ---
     sliding_window: int = 0     # 0 = full causal attention
@@ -59,7 +66,14 @@ class ArchConfig:
     frontend: Optional[str] = None   # None | "audio" | "vision"
     frontend_tokens: int = 0         # patch/frame embeddings prepended
 
+    # --- muP-style multipliers (granite); the defaults are the identity ---
+    embedding_multiplier: float = 1.0   # token embeddings x this
+    residual_multiplier: float = 1.0    # every residual branch x this
+    attention_multiplier: float = 0.0   # score scale; 0 -> 1/sqrt(head_dim)
+    logits_scaling: float = 1.0         # logits / this
+
     # --- misc ---
+    use_rope: bool = True       # False: no positional encoding (NoPE)
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -77,6 +91,9 @@ class ArchConfig:
 
     def block_kinds(self) -> Tuple[str, ...]:
         """Per-layer block kinds, in order."""
+        if self.layer_types:
+            return tuple(LAYER_TYPES[t]
+                         for t in self.layer_types[:self.num_layers])
         kinds = []
         for i in range(self.num_layers):
             if self.family == "ssm":
@@ -153,6 +170,8 @@ class ArchConfig:
                 total += attn + router + experts + shared + norms
             elif kind == MAMBA:
                 total += self._mamba_params()
+            elif kind == MAMBA_MLP:
+                total += self._mamba_params() + mlp + d
             elif kind == SHARED_ATTN:
                 pass  # counted once below
         if self.family == "hybrid" and self.hybrid_attn_every:
@@ -167,13 +186,15 @@ class ArchConfig:
         return total
 
     def _mamba_params(self) -> int:
+        """One Mamba2 block with its pre-norm (one B/C group)."""
         d = self.d_model
         d_inner = self.ssm_expand * d
         nheads = d_inner // self.ssm_head_dim
-        in_proj = d * (2 * d_inner + 2 * nheads * self.ssm_state + nheads)
-        conv = self.ssm_conv_width * (d_inner + 2 * nheads * self.ssm_state)
+        conv_dim = d_inner + 2 * self.ssm_state
+        in_proj = d * (2 * d_inner + 2 * self.ssm_state + nheads)
+        conv = (self.ssm_conv_width + 1) * conv_dim      # weights + bias
         out = d_inner * d
-        extra = 2 * nheads + d_inner  # A_log, D, dt_bias-ish + norm
+        extra = 3 * nheads + d_inner  # A_log, D, dt_bias + gate norm
         return in_proj + conv + out + extra + d
 
     def active_param_count(self) -> int:

@@ -565,17 +565,28 @@ class _BlockRunner:
     produce — so the manual route is 1-D only. Pool state stages in
     the flat (``shards == 1``) layout.
 
+    ``clients_per_pass`` (flat and 1-D mesh routes, strategies whose
+    ``server_reads_mean`` is set; see ``clients_per_pass``) runs each
+    round's (local) cohort in passes of that many clients under
+    ``lax.scan``: a pass vmaps the client phase over its clients and
+    adds their weighted uplinks to a float32 sum that the scan carries,
+    so only one pass's working copies of phi are live at a time. The
+    server step reads the sum (psum-reduced across the client axis on a
+    mesh) through ``strategy.server_apply_mean``. None is the whole
+    cohort in one vmap, the route above, unchanged.
+
     ``trace_count`` increments once per jit trace; with the engine's
     fixed per-run block shape it stays at 1 per (strategy, beta,
-    channel, schedule-shape, pool-shape, masked, mesh) config — the
-    retrace-free contract's observable.
+    channel, schedule-shape, pool-shape, masked, mesh, clients per
+    pass) config — the retrace-free contract's observable.
     """
 
     def __init__(self, strategy, beta, channel: CommChannel,
                  scheduled: bool = False, pooled: bool = False,
                  buffered: Optional[BufferedAggregation] = None,
                  mesh: Optional[Mesh] = None,
-                 masked: Optional[bool] = None, partitioner=None):
+                 masked: Optional[bool] = None, partitioner=None,
+                 clients_per_pass: Optional[int] = None):
         self.trace_count = 0
         # 2-D (clients, model) meshes take the GSPMD route: axis=None
         # selects the global block bodies (no manual shard_map, no named
@@ -609,6 +620,13 @@ class _BlockRunner:
             strategy.server_aggregate)
         server_aggregate_weighted = jax.named_scope("server_update")(
             strategy.server_aggregate_weighted)
+        if clients_per_pass is not None:
+            if pooled or (mesh is not None and axis is None):
+                raise ValueError("clients_per_pass runs on the flat and "
+                                 "1-D mesh routes only (engine-internal "
+                                 "invariant)")
+            server_apply_mean = jax.named_scope("server_update")(
+                strategy.server_apply_mean)
 
         @jax.named_scope("client_phase")
         def client_phase(phi, sched, batch, masks, chunk_ids):
@@ -641,6 +659,39 @@ class _BlockRunner:
                     masks=m if ref is not None else None)
             return results, losses
 
+        def uplink_sum_by_passes(phi, sched, batch, masks, chunk_ids):
+            """The (local) cohort's weighted uplink sum, in passes of
+            ``clients_per_pass`` clients under ``lax.scan``, and every
+            client's losses. Uniform rounds weigh each client 1/C;
+            zero-weight clients add nothing (``weighted_client_mean``'s
+            rule)."""
+            from repro.core.strategies import weighted_client_mean
+            c = jax.tree.leaves(batch)[0].shape[0]
+            n = c // clients_per_pass
+            w = (sched.weights if scheduled
+                 else jnp.full((c,), 1.0 / c, jnp.float32))
+
+            def split(a):
+                return a.reshape((n, clients_per_pass) + a.shape[1:])
+
+            def one_pass(total, xs):
+                b, k, wp = xs
+                results, losses = client_phase(
+                    phi, ClientSchedule(sched.valid, sched.alpha,
+                                        sched.round_index, None, k, wp),
+                    b, masks, chunk_ids)
+                total = jax.tree.map(jnp.add, total,
+                                     weighted_client_mean(results, wp))
+                return total, losses
+
+            total = jax.tree.map(lambda q: jnp.zeros(q.shape, jnp.float32),
+                                 strategy.uplink_template(phi))
+            total, losses = jax.lax.scan(
+                one_pass, total,
+                (jax.tree.map(split, batch), split(sched.local_steps),
+                 split(w)))
+            return total, losses.reshape((c,) + losses.shape[2:])
+
         def weighted_round_loss(losses, sched):
             k = jnp.maximum(sched.local_steps, 1).astype(jnp.float32)
             per_client = losses.reshape(
@@ -659,6 +710,18 @@ class _BlockRunner:
                 #                      LOCAL cohort shard on mesh runs
 
                 def live(phi):
+                    if clients_per_pass is not None:
+                        mean, losses = uplink_sum_by_passes(
+                            phi, sched, batch, masks, chunk_ids)
+                        if axis is not None:
+                            # one multi-operand all-reduce of the
+                            # per-shard sums, as weighted_client_mean
+                            mean = jax.lax.psum(mean, axis)
+                        phi = server_apply_mean(phi, mean, sched.alpha,
+                                                beta_f)
+                        loss = (weighted_round_loss(losses, sched)
+                                if scheduled else jnp.mean(losses))
+                        return phi, loss
                     results, losses = client_phase(phi, sched, batch,
                                                    masks, chunk_ids)
                     if axis is not None:
@@ -1114,7 +1177,8 @@ def _block_runner(strategy, beta, channel: CommChannel,
                   buffered: Optional[BufferedAggregation] = None,
                   mesh: Optional[Mesh] = None,
                   masked: Optional[bool] = None,
-                  partitioner=None) -> _BlockRunner:
+                  partitioner=None,
+                  clients_per_pass: Optional[int] = None) -> _BlockRunner:
     """Strategies and channels are frozen dataclasses, so identically-
     configured runs (every test/bench re-entry) reuse one jitted runner
     instead of recompiling per call; ``scheduled`` (the policy's static
@@ -1131,11 +1195,12 @@ def _block_runner(strategy, beta, channel: CommChannel,
     logged so sweeps notice."""
     masked = bool(scheduled) if masked is None else bool(masked)
     key = (strategy, float(beta), channel, bool(scheduled), bool(pooled),
-           buffered, masked, partitioner, mesh)
+           buffered, masked, partitioner, clients_per_pass, mesh)
 
     def build():
         return _BlockRunner(strategy, beta, channel, scheduled, pooled,
-                            buffered, mesh, masked, partitioner)
+                            buffered, mesh, masked, partitioner,
+                            clients_per_pass)
 
     try:
         return _RUNNER_CACHE.get(key, build)
@@ -1148,6 +1213,87 @@ def _block_runner(strategy, beta, channel: CommChannel,
             "them.", _UNHASHABLE_MISSES["count"],
             type(strategy).__name__, type(channel).__name__)
         return build()
+
+
+def _memory_budget(device) -> Optional[int]:
+    """Bytes a program may hold on ``device``: the limit its allocator
+    reports, or None where it reports none (the CPU)."""
+    stats = device.memory_stats()
+    limit = (stats or {}).get("bytes_limit")
+    return int(limit) if limit else None
+
+
+_CLIENT_STEP_BYTES: Dict = {}
+#: how far under the device's memory a cohort's parameters and data,
+#: four times over, must stay to be taken whole without measuring
+_SHAPE_MARGIN = 64
+
+
+def _client_step_bytes(strategy, beta, phi, batch):
+    """Device bytes one client's update holds beyond the broadcast phi
+    and its data: its output (the uplink) and its temporaries (the
+    working copy's gradients and activations), and the bytes of its
+    code, read from the compiled ``client_update`` of one client at these
+    shapes. Memoized per shape set (unhashable strategies excepted), so a
+    run's calls compile it once."""
+    spec = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                        (phi, batch))
+
+    def measure():
+        beta_f = jnp.float32(beta)
+        mem = jax.jit(lambda p, b: strategy.client_update(p, b, beta_f)) \
+            .lower(*spec).compile().memory_analysis()
+        return (int(mem.output_size_in_bytes + mem.temp_size_in_bytes),
+                int(mem.generated_code_size_in_bytes))
+
+    key = (strategy, float(beta),
+           tuple((l.shape, str(l.dtype)) for l in jax.tree.leaves(spec)),
+           jax.tree.structure(spec))
+    try:
+        hit = _CLIENT_STEP_BYTES.get(key)
+    except TypeError:                    # an unhashable strategy
+        return measure()
+    if hit is None:
+        hit = _CLIENT_STEP_BYTES[key] = measure()
+    return hit
+
+
+def clients_per_pass(strategy, beta, phi, batch, clients: int,
+                     device) -> int:
+    """How many of a round's ``clients`` (the cohort, or one device's
+    shard of it) the client phase runs in one pass: all of them where
+    their working copies fit the device's memory, else the largest
+    divisor of ``clients`` that fits, and at least one.
+
+    ``batch`` is one client's data. The fixed part is phi, the float32
+    uplink sum the passes carry, the round's data and the program's code
+    (taken as the update's); each client adds what its compiled update
+    holds (``_client_step_bytes``). Only strategies whose server step
+    reads the uplinks' mean alone (``server_reads_mean``) can be split;
+    the others, and devices that report no memory, take the whole
+    cohort. So does a cohort whose parameters and data, four times over,
+    fill under 1/``_SHAPE_MARGIN`` of the device's memory, with no
+    update compiled to measure it: the rest is left for activations and
+    tile padding (the paper models' cohorts fill under 1/250)."""
+    if clients <= 1 or not getattr(strategy, "server_reads_mean", False):
+        return clients
+    budget = _memory_budget(device)
+    if budget is None:
+        return clients
+    param_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(phi))
+    client_data = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(batch))
+    if 4 * clients * (param_bytes + client_data) * _SHAPE_MARGIN <= budget:
+        return clients
+    data_bytes = clients * client_data
+    step, code = _client_step_bytes(strategy, beta, phi, batch)
+    fixed = param_bytes + 4 * sum(a.size for a in jax.tree.leaves(phi)) \
+        + data_bytes + code
+    fit = max(1, (budget - fixed) // max(step, 1))
+    if fit >= clients:
+        return clients
+    return max(d for d in range(1, int(fit) + 1) if clients % d == 0)
 
 
 def runner_cache_stats() -> Dict[str, int]:
@@ -1168,8 +1314,10 @@ def clear_runner_cache() -> None:
     """Drop every cached jitted block runner — mesh-keyed sharded
     runners included — and reset the counters. Long sweeps over many
     strategy/channel/topology configs should call this between phases
-    so up to 64 stale executables don't stay pinned."""
+    so up to 64 stale executables don't stay pinned. Also drops the
+    memoized client-step sizes of ``clients_per_pass``."""
     _RUNNER_CACHE.clear()
+    _CLIENT_STEP_BYTES.clear()
     _UNHASHABLE_MISSES["count"] = 0
 
 
@@ -1405,6 +1553,10 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
         # private copy: the block runner donates its phi argument, and the
         # caller's init_params must stay usable (they are reused across runs)
         phi = jax.tree.map(jnp.array, init_params)
+        # from here on only the shapes: a caller that keeps no reference of
+        # its own frees its copy now, not when the call returns
+        init_params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), init_params)
         history: List[Dict] = []
         comm_bytes = 0
         start_round = 0
@@ -1417,9 +1569,12 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
         # identical at k == budget, without the per-inner-step overhead)
         masked = scheduled and not uniform
         budget = int(strategy.local_step_budget(support))
-        run_block = _block_runner(strategy, beta, channel, scheduled,
-                                  pooled=pooled, buffered=buffered, mesh=mesh,
-                                  masked=masked, partitioner=partitioner)
+        # built at the first dispatch, when the staged block gives one
+        # client's data shapes for the clients-per-pass choice
+        run_block = None
+        local_clients = c_pad // shards
+        per_pass = local_clients
+        run_tokens = 0
         # FedBuff buffers stage whatever the strategy uplinks — sized from
         # its template so quantized strategies buffer int8 trees at int8
         # width, never dequantized copies
@@ -1697,7 +1852,32 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
                         ls, st, ck, pool_state.buf_updates,
                         pool_state.buf_round, pool_state.buf_count,
                         pool_state.flushes)
-                with TraceAnnotation("engine.dispatch", block=i, rounds=blk):
+                if run_block is None:
+                    if not (pooled or model_sharded):
+                        one_client = jax.tree.map(
+                            lambda a: jax.ShapeDtypeStruct(a.shape[2:],
+                                                           a.dtype), batch_d)
+                        per_pass = clients_per_pass(
+                            strategy, beta, phi, one_client, local_clients,
+                            mesh.devices.flat[0] if mesh is not None
+                            else device or jax.devices()[0])
+                    run_block = _block_runner(
+                        strategy, beta, channel, scheduled, pooled=pooled,
+                        buffered=buffered, mesh=mesh, masked=masked,
+                        partitioner=partitioner,
+                        clients_per_pass=(per_pass if per_pass < local_clients
+                                          else None))
+                    run_span.set_metadata(clients_per_pass=per_pass)
+                    if tracker is not None:
+                        tracker.gauge("engine.clients_per_pass", per_pass)
+                x = batch_d.get("x") if isinstance(batch_d, dict) else None
+                tokens = (int(part.sum()) * int(np.prod(x.shape[2:]))
+                          if x is not None
+                          and jnp.issubdtype(x.dtype, jnp.integer) else 0)
+                run_tokens += tokens
+                with TraceAnnotation("engine.dispatch", block=i, rounds=blk,
+                                     clients_per_pass=per_pass,
+                                     tokens=tokens):
                     if pooled:
                         phi, pool_state, round_losses = run_block(
                             phi, pool_state, sched_d, batch_d)
@@ -1801,6 +1981,7 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
                             save_round_state(ckpt_dir, state, keep=ckpt_keep)
             if writer is not None:
                 writer.close()      # drain pending snapshots; surface errors
+            run_span.set_metadata(tokens=run_tokens)
         finally:
             staged_iter.close()
             if writer is not None:

@@ -42,6 +42,16 @@ schedule-aware variants instead:
       The full per-client workload in scheduler units; scheduling
       policies draw each k_i from [1, budget].
 
+Strategies whose server step reads nothing of the uplinks but their
+(weighted) mean set ``server_reads_mean`` and define
+
+  server_apply_mean(phi, mean, alpha_t, beta) -> phi
+      mean: the float32 weighted mean of the round's uplinks.
+
+The engine may then run a cohort whose working copies do not fit the
+device together in passes of fewer clients, carrying the uplinks' sum
+instead of their stack (``repro.core.engine.clients_per_pass``).
+
 A new algorithm is one strategy object — not a new file-long loop.
 """
 from __future__ import annotations
@@ -133,6 +143,8 @@ class FedStrategy:
     #                              uplinks), "zeros" (gradient uplinks),
     #                              or "none" (no reference; transmit the
     #                              result tree as-is)
+    server_reads_mean = False    # server step reads only the uplinks'
+    #                              mean (defines server_apply_mean)
     payload_dtype = "float32"    # wire dtype of the client result tree.
     #                              "float32" (default) leaves transport
     #                              simulation to the CommChannel;
@@ -171,6 +183,9 @@ class FedStrategy:
         del k
         return self.client_update(phi, client_batch, beta)
 
+    def server_apply_mean(self, phi, mean, alpha_t, beta):
+        raise NotImplementedError
+
     def server_aggregate_weighted(self, phi, client_results, alpha_t,
                                   beta, weights, axis_name=None):
         raise NotImplementedError(
@@ -190,10 +205,15 @@ class TinyReptileStrategy(FedStrategy):
 
     data_mode = "stream"
     tracks_inner_loss = True
+    server_reads_mean = True
 
     def client_update(self, phi, client_batch, beta):
         return finetune_online(self.loss_fn, phi,
                                client_batch["x"], client_batch["y"], beta)
+
+    def server_apply_mean(self, phi, mean, alpha_t, beta):
+        return meta_interpolate(phi, mean, alpha_t,
+                                use_pallas=self.use_pallas)
 
     def client_update_steps(self, phi, client_batch, beta, k):
         """Straggler clients consume only their first k stream samples."""
@@ -221,10 +241,15 @@ class ReptileStrategy(FedStrategy):
     use_pallas: Optional[bool] = None
 
     tracks_inner_loss = True
+    server_reads_mean = True
 
     def client_update(self, phi, client_batch, beta):
         return finetune_batch(self.loss_fn, phi, client_batch,
                               self.epochs, beta)
+
+    def server_apply_mean(self, phi, mean, alpha_t, beta):
+        return meta_interpolate(phi, mean, alpha_t,
+                                use_pallas=self.use_pallas)
 
     def local_step_budget(self, support):
         return self.epochs

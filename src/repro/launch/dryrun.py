@@ -90,8 +90,8 @@ def build_step_and_args(cfg, shape, mesh, step_kind):
 
 def _probe_period(cfg):
     """Layer-count granularity for cost probes."""
-    if cfg.family == "hybrid":
-        return max(cfg.hybrid_attn_every, 1)
+    if cfg.hybrid_attn_every:
+        return cfg.hybrid_attn_every
     from repro.models.transformer import find_period, layer_specs
     return find_period(layer_specs(cfg))
 

@@ -16,7 +16,10 @@ Counts ride on the spans as arguments. Nothing opens a span per client,
 sample or request.
 
 - ``engine.run``: one ``run_federated`` call; ``rounds``, ``clients``,
-  ``seed``, ``blocks``.
+  ``seed``, ``blocks``, ``clients_per_pass`` (how many clients' working
+  copies one pass of the client phase holds; ``clients`` where the whole
+  cohort fits) and ``tokens`` (the support tokens the call streamed; 0
+  where the client data are not token sequences).
 - Per block, each with ``block`` (its index in the call) and ``rounds``
   (the block's rounds), which pair the prefetch thread's spans with the
   consumer's:
@@ -26,7 +29,8 @@ sample or request.
   identity window is a second one, on the consumer), ``engine.wait``
   (the consumer waiting for the block; with no prefetch thread it holds
   the block's plan, sample and stage), ``engine.dispatch`` (the block
-  runner call), ``engine.fetch`` (the tracker's loss fetch and the
+  runner call; ``clients_per_pass``, and ``tokens``, the block's share
+  of the run's), ``engine.fetch`` (the tracker's loss fetch and the
   host-resident pool's write-back), ``engine.eval``, ``engine.ckpt``.
 - ``serve.tick``: one ``AdaptationServer.step`` that runs a tick;
   ``tick`` (its index), ``admitted``, ``retired``, ``live`` (the
@@ -38,6 +42,8 @@ sample or request.
   tick; ``bytes``: the packed refill's size), ``serve.readback`` (the
   one read of the packed ``finished``, step counts and query losses;
   ``bytes``: its size) and ``serve.retire``.
+
+An attached tracker gets the gauge ``engine.clients_per_pass``.
 
 The device programs name their parts with ``jax.named_scope``: the
 block runner's ``client_phase`` and ``server_update``, the tick's

@@ -110,16 +110,23 @@ def _banded_attention(q, k, v, *, window, scale, q_block=512):
 
 
 def flash_attention(q, k, v, *, causal, window=0, q_positions=None,
-                    kv_positions=None, q_block=512, kv_block=512):
+                    kv_positions=None, q_block=512, kv_block=512,
+                    scale=None, remat_q_blocks=False):
     """Blockwise online-softmax attention.
 
     q: (B, Sq, H, hd); k, v: (B, Skv, Kv, hd). H = Kv * R (GQA).
+    ``scale`` multiplies the scores (None: 1/sqrt(hd)).
+    ``remat_q_blocks`` recomputes each q block in the backward pass, so
+    that only its inputs stay live and not its (nk, qb, kb) score tiles:
+    for a caller that rematerialises the block around it one by one
+    (an unscanned layer stack), not for one inside a checkpointed scan.
     Returns (B, Sq, H, hd).
     """
     B, Sq, H, hd = q.shape
     Skv, Kv = k.shape[1], k.shape[2]
     R = H // Kv
-    scale = hd ** -0.5
+    if scale is None:
+        scale = hd ** -0.5
 
     if q_positions is None:
         q_positions = jnp.arange(Sq)
@@ -208,21 +215,26 @@ def flash_attention(q, k, v, *, causal, window=0, q_positions=None,
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return None, out.transpose(0, 3, 1, 2, 4)  # (B,qb,Kv,R,hd)
 
+    if remat_q_blocks:
+        q_step = jax.checkpoint(q_step, prevent_cse=False)
     _, outs = jax.lax.scan(q_step, None, (qg, qp))
     out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, nq * q_block, H, hd)
     return out[:, :Sq].astype(q.dtype)
 
 
-def decode_attention(q, k_cache, v_cache, cache_len, *, window=0):
+def decode_attention(q, k_cache, v_cache, cache_len, *, window=0,
+                     scale=None):
     """Single-token attention against a (possibly seq-sharded) KV cache.
 
     q: (B, 1, H, hd); k_cache/v_cache: (B, S, Kv, hd); cache_len: scalar or
     (B,) number of valid cache entries. New token attends to cache[:len].
+    ``scale`` multiplies the scores (None: 1/sqrt(hd)).
     """
     B, _, H, hd = q.shape
     S, Kv = k_cache.shape[1], k_cache.shape[2]
     R = H // Kv
-    scale = hd ** -0.5
+    if scale is None:
+        scale = hd ** -0.5
     qg = q.reshape(B, Kv, R, hd) * jnp.asarray(scale, q.dtype)
     s = jnp.einsum("bkrh,bskh->bkrs", qg, k_cache,
                    preferred_element_type=jnp.float32)
@@ -242,8 +254,11 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, window=0):
 
 
 def attention_block(params, x, *, num_kv_heads, rope_theta, causal=True,
-                    window=0, positions=None, kv_x=None, use_rope=True):
-    """Full attention sub-block (projections + flash). kv_x for cross-attn."""
+                    window=0, positions=None, kv_x=None, use_rope=True,
+                    scale=None, remat_q_blocks=False):
+    """Full attention sub-block (projections + flash). kv_x for cross-attn;
+    ``scale`` multiplies the scores (None: 1/sqrt(head_dim));
+    ``remat_q_blocks`` as in ``flash_attention``."""
     B, S, _ = x.shape
     src = x if kv_x is None else kv_x
     q = jnp.einsum("bsd,dnh->bsnh", x, params["wq"])
@@ -262,7 +277,8 @@ def attention_block(params, x, *, num_kv_heads, rope_theta, causal=True,
         v = shard(v, "batch", None, None, None)
     out = flash_attention(q, k, v, causal=causal, window=window,
                           q_positions=positions if kv_x is None else None,
-                          kv_positions=positions if kv_x is None else None)
+                          kv_positions=positions if kv_x is None else None,
+                          scale=scale, remat_q_blocks=remat_q_blocks)
     if feature("seqpar"):
         out = shard(out, "batch", "model", None, None)
     return jnp.einsum("bsnh,nhd->bsd", out, params["wo"])
@@ -270,7 +286,7 @@ def attention_block(params, x, *, num_kv_heads, rope_theta, causal=True,
 
 def decode_attention_block(params, x, k_cache, v_cache, cache_len, *,
                            rope_theta, window=0, use_rope=True,
-                           update_cache=True):
+                           update_cache=True, scale=None):
     """Decode sub-block: project 1 token, append to cache, attend.
 
     With the ``ringkv`` lever active and a cache sized to the sliding
@@ -296,9 +312,10 @@ def decode_attention_block(params, x, k_cache, v_cache, cache_len, *,
             v_cache, v.astype(v_cache.dtype), write_at, axis=1)
     if ring:
         valid = jnp.minimum(cache_len + 1, S_cache)
-        out = decode_attention(q, k_cache, v_cache, valid, window=0)
+        out = decode_attention(q, k_cache, v_cache, valid, window=0,
+                               scale=scale)
     else:
         out = decode_attention(q, k_cache, v_cache, cache_len + 1,
-                               window=window)
+                               window=window, scale=scale)
     out = jnp.einsum("bsnh,nhd->bsd", out, params["wo"])
     return out, k_cache, v_cache

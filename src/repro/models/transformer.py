@@ -3,7 +3,8 @@ whisper encoder-decoder, built from an ArchConfig.
 
 Homogeneous layer stacks use scan-over-layers (params stacked over
 pattern groups) to keep HLO compact; small / irregular stacks (whisper,
-zamba2 hybrid) use python loops.
+zamba2 hybrid, a single period of a published ``layer_types`` list) use
+python loops, whose blocks the training loss rematerialises one by one.
 """
 from __future__ import annotations
 
@@ -14,7 +15,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ATTN, MAMBA, MOE, SHARED_ATTN, ArchConfig
+from repro.configs.base import (ATTN, MAMBA, MAMBA_MLP, MOE, SHARED_ATTN,
+                                ArchConfig)
 from repro.models import attention as attn_lib
 from repro.models import mamba2 as mamba_lib
 from repro.models import moe as moe_lib
@@ -43,7 +45,7 @@ def layer_specs(cfg: ArchConfig) -> List[Tuple[str, int]]:
         elif kind == SHARED_ATTN:
             specs.append((SHARED_ATTN, cfg.sliding_window))
         else:
-            specs.append((MAMBA, 0))
+            specs.append((kind, 0))
     return specs
 
 
@@ -72,13 +74,17 @@ def _init_block(cfg: ArchConfig, kind: str, key, *, cross: bool) -> Dict[str, An
     d, dtype = cfg.d_model, jnp.dtype(cfg.dtype)
     hd = cfg.resolved_head_dim
     ks = jax.random.split(key, 6)
-    if kind == MAMBA:
-        return {
+    if kind in (MAMBA, MAMBA_MLP):
+        p = {
             "norm1": jnp.zeros((d,), dtype),
             "mamba": mamba_lib.init_mamba(
                 ks[0], d, cfg.ssm_state, cfg.ssm_head_dim, cfg.ssm_expand,
                 cfg.ssm_conv_width, dtype),
         }
+        if kind == MAMBA_MLP:
+            p["norm2"] = jnp.zeros((d,), dtype)
+            p["mlp"] = init_mlp(ks[2], d, cfg.d_ff, cfg.act, dtype)
+        return p
     p = {
         "norm1": jnp.zeros((d,), dtype),
         "attn": attn_lib.init_attention(ks[0], d, cfg.num_heads,
@@ -97,29 +103,46 @@ def _init_block(cfg: ArchConfig, kind: str, key, *, cross: bool) -> Dict[str, An
     return p
 
 
+def _residual(cfg: ArchConfig, x, h):
+    """x + residual_multiplier * h (plain x + h at the default 1)."""
+    if cfg.residual_multiplier == 1.0:
+        return x + h
+    return x + jnp.asarray(cfg.residual_multiplier, h.dtype) * h
+
+
 def _apply_block(cfg: ArchConfig, kind: str, window: int, bp, x, *,
-                 positions=None, enc_out=None, use_rope=True):
-    """Forward one block (train/prefill). Returns (x, aux)."""
+                 positions=None, enc_out=None, use_rope=True,
+                 remat_q_blocks=False):
+    """Forward one block (train/prefill). Returns (x, aux).
+    ``remat_q_blocks``: see ``attention.flash_attention``."""
     eps = cfg.norm_eps
     aux = jnp.zeros((), jnp.float32)
-    if kind == MAMBA:
+    if kind in (MAMBA, MAMBA_MLP):
         h = mamba_lib.mamba_block(
             bp["mamba"], rms_norm(x, bp["norm1"], eps),
             d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
             expand=cfg.ssm_expand, conv_width=cfg.ssm_conv_width,
             chunk=cfg.ssm_chunk, norm_eps=eps)
-        return x + h, aux
+        x = _residual(cfg, x, h)
+        if kind == MAMBA:
+            return x, aux
+        y = mlp(bp["mlp"], rms_norm(x, bp["norm2"], eps), cfg.act)
+        return _residual(cfg, x, y), aux
     h = attn_lib.attention_block(
         bp["attn"], rms_norm(x, bp["norm1"], eps),
         num_kv_heads=cfg.num_kv_heads, rope_theta=cfg.rope_theta,
-        causal=True, window=window, positions=positions, use_rope=use_rope)
+        causal=True, window=window, positions=positions,
+        use_rope=use_rope and cfg.use_rope,
+        scale=cfg.attention_multiplier or None,
+        remat_q_blocks=remat_q_blocks)
     h = shard(h, "batch", None, None)
-    x = x + h
+    x = _residual(cfg, x, h)
     if "cross" in bp:
         c = attn_lib.attention_block(
             bp["cross"], rms_norm(x, bp["norm_x"], eps),
             num_kv_heads=cfg.num_kv_heads, rope_theta=cfg.rope_theta,
-            causal=False, kv_x=enc_out, use_rope=False)
+            causal=False, kv_x=enc_out, use_rope=False,
+            remat_q_blocks=remat_q_blocks)
         x = x + c
     y_in = rms_norm(x, bp["norm2"], eps)
     if kind == MOE:
@@ -128,7 +151,7 @@ def _apply_block(cfg: ArchConfig, kind: str, window: int, bp, x, *,
     else:
         y = mlp(bp["mlp"], y_in, cfg.act)
     y = shard(y, "batch", None, None)
-    return x + y, aux
+    return _residual(cfg, x, y), aux
 
 
 # ---------------------------------------------------------------------------
@@ -170,7 +193,8 @@ class Model:
 
     @property
     def is_hybrid(self):
-        return self.cfg.family == "hybrid"
+        """zamba2's layout: one weight-shared block every n Mamba blocks."""
+        return self.cfg.hybrid_attn_every > 0
 
     @property
     def is_encdec(self):
@@ -265,7 +289,7 @@ class Model:
         """Token (+frontend) embedding. Returns (x, enc_out, text_offset)."""
         cfg = self.cfg
         tokens = batch["tokens"]
-        x = params["embed"][tokens]
+        x = self._embed_tokens(params, tokens)
         x = shard(x, "batch", None, None)
         enc_out = None
         offset = 0
@@ -279,8 +303,16 @@ class Model:
                 jnp.arange(x.shape[1]), cfg.d_model).astype(x.dtype)
         return x, enc_out, offset
 
-    def _backbone(self, params, x, *, enc_out=None):
-        """Run all blocks. Returns (hidden, aux_loss)."""
+    def _embed_tokens(self, params, tokens):
+        x = params["embed"][tokens]
+        if self.cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(self.cfg.embedding_multiplier, x.dtype)
+        return x
+
+    def _backbone(self, params, x, *, enc_out=None, remat=False):
+        """Run all blocks. Returns (hidden, aux_loss). ``remat``
+        rematerialises each block of an unscanned stack in the backward
+        pass, so that only block inputs stay live."""
         cfg = self.cfg
         specs = self.specs
         use_rope = not self.is_encdec
@@ -337,11 +369,18 @@ class Model:
                 body, (x, jnp.zeros((), jnp.float32)), params["layers"])
             return x, aux
 
+        from repro.runtime.flags import probe_mode
+        remat = remat and not probe_mode()
         aux = jnp.zeros((), jnp.float32)
         for bp, (kind, window) in zip(params["layers"], specs):
-            x, a = _apply_block(cfg, kind, window, bp, x,
-                                positions=positions, enc_out=enc_out,
-                                use_rope=use_rope)
+            block = functools.partial(
+                _apply_block, cfg, kind, window, positions=positions,
+                enc_out=enc_out, use_rope=use_rope, remat_q_blocks=remat)
+            if remat:
+                # unscanned: CSE must not merge the recomputation with
+                # the forward pass, or every activation stays live
+                block = jax.checkpoint(block)
+            x, a = block(bp, x)
             aux = aux + a
         return x, aux
 
@@ -350,17 +389,25 @@ class Model:
             return params["embed"].T
         return params["lm_head"]
 
+    def _logits_input(self, x):
+        """The final hidden state as the head sees it: divided by
+        ``logits_scaling``, which divides the logits."""
+        if self.cfg.logits_scaling == 1.0:
+            return x
+        return x / jnp.asarray(self.cfg.logits_scaling, x.dtype)
+
     # ----- training loss ---------------------------------------------------
     def loss_fn(self, params, batch):
         """Mean next-token cross-entropy (+ MoE aux)."""
         cfg = self.cfg
         x, enc_out, offset = self._embed_inputs(params, batch)
-        x, aux = self._backbone(params, x, enc_out=enc_out)
+        x, aux = self._backbone(params, x, enc_out=enc_out, remat=True)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         if offset:
             x = x[:, offset:]
         labels = batch["labels"]
-        loss = chunked_cross_entropy(x, self._lm_head(params), labels)
+        loss = chunked_cross_entropy(self._logits_input(x),
+                                     self._lm_head(params), labels)
         return loss + AUX_LOSS_WEIGHT * aux
 
     # ----- prefill ----------------------------------------------------------
@@ -376,7 +423,7 @@ class Model:
         x, enc_out, offset = self._embed_inputs(params, batch)
         x, _ = self._backbone(params, x, enc_out=enc_out)
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        last = x[:, -1:]
+        last = self._logits_input(x[:, -1:])
         logits = (last @ self._lm_head(params)).astype(jnp.float32)
         return logits
 
@@ -418,7 +465,7 @@ class Model:
             n_groups = len(specs) // p
             layers = []
             for kind, window in specs[:p]:
-                if kind == MAMBA:
+                if kind in (MAMBA, MAMBA_MLP):
                     layers.append(_mamba_cache(cfg, batch_size, dtype,
                                                stack=n_groups))
                 else:
@@ -428,7 +475,7 @@ class Model:
             return {"layers": layers}
         layers = []
         for kind, window in specs:
-            if kind == MAMBA:
+            if kind in (MAMBA, MAMBA_MLP):
                 layers.append(_mamba_cache(cfg, batch_size, dtype))
             else:
                 layers.append(_attn_cache(cfg, batch_size, eff_seq(window),
@@ -450,20 +497,25 @@ class Model:
         """Apply one decode block. Returns (x, new_cache_entry)."""
         cfg = self.cfg
         eps = cfg.norm_eps
-        if kind == MAMBA:
+        if kind in (MAMBA, MAMBA_MLP):
             h = rms_norm(x, bp["norm1"], eps)
             out, conv_new, ssm_new = mamba_lib.mamba_decode_block(
                 bp["mamba"], h, cache_entry["conv"], cache_entry["ssm"],
                 d_state=cfg.ssm_state, head_dim=cfg.ssm_head_dim,
                 expand=cfg.ssm_expand, conv_width=cfg.ssm_conv_width,
                 norm_eps=eps)
-            return x + out, {"conv": conv_new, "ssm": ssm_new}
+            x = _residual(cfg, x, out)
+            if kind == MAMBA_MLP:
+                x = _residual(cfg, x, mlp(
+                    bp["mlp"], rms_norm(x, bp["norm2"], eps), cfg.act))
+            return x, {"conv": conv_new, "ssm": ssm_new}
         h = rms_norm(x, bp["norm1"], eps)
         out, k_new, v_new = attn_lib.decode_attention_block(
             bp["attn"], h, cache_entry["k"], cache_entry["v"], cache_len,
             rope_theta=cfg.rope_theta, window=window,
-            use_rope=not self.is_encdec)
-        x = x + out
+            use_rope=not self.is_encdec and cfg.use_rope,
+            scale=cfg.attention_multiplier or None)
+        x = _residual(cfg, x, out)
         if "cross" in bp and cross_entry is not None:
             q = jnp.einsum("bsd,dnh->bsnh",
                            rms_norm(x, bp["norm_x"], eps), bp["cross"]["wq"])
@@ -477,7 +529,7 @@ class Model:
                                      experts_per_token=cfg.experts_per_token)
         else:
             y = mlp(bp["mlp"], y_in, cfg.act)
-        return x + y, {"k": k_new, "v": v_new}
+        return _residual(cfg, x, y), {"k": k_new, "v": v_new}
 
     def decode_fn(self, params, batch):
         """One decode step. batch: tokens (B,1), cache, cache_len (scalar).
@@ -488,7 +540,7 @@ class Model:
         eps = cfg.norm_eps
         tokens, cache, cache_len = (batch["tokens"], batch["cache"],
                                     batch["cache_len"])
-        x = params["embed"][tokens]
+        x = self._embed_tokens(params, tokens)
         if self.is_encdec:
             pos = jnp.full((tokens.shape[0], 1), cache_len)
             x = x + _sinusoidal(pos, cfg.d_model).astype(x.dtype)
@@ -538,7 +590,7 @@ class Model:
                         cache["tail_mamba"][pos], cache_len)
                     new_tail.append(e)
                 new_cache["tail_mamba"] = new_tail
-            x = rms_norm(x, params["final_norm"], eps)
+            x = self._logits_input(rms_norm(x, params["final_norm"], eps))
             logits = (x @ self._lm_head(params)).astype(jnp.float32)
             return logits, new_cache
 
@@ -572,7 +624,7 @@ class Model:
             new_cache = dict(cache)
             new_cache["layers"] = new_layers
 
-        x = rms_norm(x, params["final_norm"], eps)
+        x = self._logits_input(rms_norm(x, params["final_norm"], eps))
         logits = (x @ self._lm_head(params)).astype(jnp.float32)
         return logits, new_cache
 

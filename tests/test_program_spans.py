@@ -79,8 +79,9 @@ def test_engine_spans_pair_by_block(tmp_path, prefetch):
         _train(prefetch)
     spans = _spans(tmp_path)
     (run,) = [s for s in spans if s["name"] == "engine.run"]
+    # sine samples are no tokens; both clients fit one pass
     assert run["args"] == {"rounds": 8, "clients": 2, "seed": 3,
-                           "blocks": 2}
+                           "blocks": 2, "clients_per_pass": 2, "tokens": 0}
     by = {}
     for s in spans:
         if s["name"] == "engine.run":
@@ -91,6 +92,8 @@ def test_engine_spans_pair_by_block(tmp_path, prefetch):
     assert set(by) == set(BLOCK_PHASES)
     assert all(set(v) == {0, 1} for v in by.values())
     assert all(s["args"]["bytes"] > 0 for s in by["engine.stage"].values())
+    assert all(s["args"]["clients_per_pass"] == 2 and s["args"]["tokens"] == 0
+               for s in by["engine.dispatch"].values())
     producer = {s["thread"] for n in BLOCK_PHASES[:3]
                 for s in by[n].values()}
     consumer = {s["thread"] for n in BLOCK_PHASES[3:]
