@@ -95,7 +95,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.checkpoint.ckpt import (AsyncCheckpointWriter, RoundState,
                                    restore_round_state, save_round_state)
 from repro.core.meta import evaluate_init
-from repro.core.pipeline import (ClientSchedule, SamplingPolicy,
+from repro.core.pipeline import (SAMPLERS, ClientSchedule, SamplingPolicy,
                                  UniformSampling, block_shardings,
                                  plan_blocks, prefetch_items,
                                  single_device_of)
@@ -1336,7 +1336,7 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
                   eval_kwargs: Optional[dict] = None,
                   channel: Optional[CommChannel] = None,
                   max_block: int = 512, prefetch: int = 2,
-                  sampler: str = "reference",
+                  sampler: Optional[str] = None,
                   sampling: Optional[SamplingPolicy] = None,
                   pool: Optional[ClientPool] = None,
                   buffered: Optional[BufferedAggregation] = None,
@@ -1359,15 +1359,28 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
     repro.core.pipeline.plan_blocks), so the block runner compiles once
     per (strategy, beta, channel, schedule-shape, pool-shape) config.
     The host only plans the per-round ClientSchedule and samples client
-    data (`sampling` policy; `sampler` picks the legacy-exact
-    "reference" RNG order or the "vectorized" fast path for the default
-    uniform policy) and runs the eval protocol — heterogeneous scenarios
-    (partial participation, stragglers, rotating partial-comm masks)
-    ride the schedule through the scan with no extra per-round host
-    dispatches. With `prefetch` > 0 a background thread plans, samples,
-    and stages block N+1 while the device runs block N (double-buffered
-    at the default 2); `prefetch=0` is the synchronous escape hatch —
-    both are bit-for-bit identical.
+    data (`sampling` policy) and runs the eval protocol — heterogeneous
+    scenarios (partial participation, stragglers, rotating partial-comm
+    masks) ride the schedule through the scan with no extra per-round
+    host dispatches. With `prefetch` > 0 a background thread plans,
+    samples, and stages block N+1 while the device runs block N
+    (double-buffered at the default 2); `prefetch=0` is the synchronous
+    escape hatch — both are bit-for-bit identical.
+
+    `sampler` names the default uniform policy's data path. Left unset,
+    a pool-free run takes each block's client data from
+    `task_dist.sample_support_block` in one call (a distribution that
+    does not override it falls back to the per-task loop), and a pooled
+    run follows `pool.sampler`. The built-in distributions draw that
+    block in block RNG order, so a seeded run gives different (but
+    identically distributed) tasks than the legacy per-round loops;
+    `sampler="reference"` replays those loops' per-task order
+    bit-for-bit. The checkpoint fingerprint records the sampler: a run
+    that names neither `sampler=` nor `sampling=` resumes a snapshot
+    under the sampler it recorded (one written under the old "reference"
+    default continues in that order), and a named one must match it.
+    With an explicit `sampling=` policy the sampler is the policy's own,
+    and `sampler=` must stay unset.
 
     `pool` switches the run onto PERSISTENT client identities (a
     repro.core.pool.ClientPool over `task_dist`): each round the policy
@@ -1432,15 +1445,17 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
                          seed=seed) as run_span:
         if channel is None:
             channel = CommChannel()
+        # a run that names neither continues a snapshot under the sampler
+        # the snapshot recorded (see the resume below)
+        sampler_named = sampler is not None or sampling is not None
         if sampling is None:
             # a pooled run's host-path contract is the POOL's sampler: a
             # vectorized (fleet-scale) pool must also seat cohorts through
             # the O(cohort) block path, not the per-round O(N) choice loop
-            if pool is not None and sampler == "reference":
-                sampling = UniformSampling(pool.sampler)
-            else:
-                sampling = UniformSampling(sampler)
-        elif sampler != "reference":
+            if sampler is None:
+                sampler = pool.sampler if pool is not None else "vectorized"
+            sampling = UniformSampling(sampler)
+        elif sampler is not None:
             # an explicit policy owns its own sampler choice; silently
             # ignoring a non-default `sampler=` string would run a different
             # host path than the caller asked for
@@ -1449,6 +1464,7 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
                 f"{type(sampling).__name__}(..., sampler={sampler!r})), not "
                 f"as run_federated(sampler=...) alongside sampling=")
         pooled = pool is not None
+        policy_sampler = getattr(sampling, "sampler", "reference")
         if buffered is not None:
             if not pooled:
                 raise ValueError("buffered aggregation needs persistent "
@@ -1607,7 +1623,7 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
                 "strategy": type(strategy).__name__,
                 "pool_size": int(pool.size) if pooled else 0,
                 "pool_sampler": pool.sampler if pooled else "",
-                "policy_sampler": getattr(sampling, "sampler", "reference"),
+                "policy_sampler": policy_sampler,
                 "buffered": buffered is not None}
         elif resume:
             raise ValueError("resume=True needs ckpt_dir= to restore from")
@@ -1621,6 +1637,11 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
                             ckpt_dir)
                 saved = None
             if saved is not None:
+                # e.g. a snapshot written when "reference" was the default
+                was = (saved.fingerprint or {}).get("policy_sampler")
+                if not sampler_named and was in SAMPLERS:
+                    policy_sampler = fingerprint["policy_sampler"] = was
+                    sampling = UniformSampling(was)
                 diff = {k: (saved.fingerprint.get(k), v)
                         for k, v in fingerprint.items()
                         if saved.fingerprint and saved.fingerprint.get(k) != v}
@@ -1647,6 +1668,13 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
                 sampling.load_state_dict(saved.host.get("sampling", {}),
                                          rng=rng)
                 logger.info("resumed %s from round %d", ckpt_dir, start_round)
+        # the data path each block's `engine.sample` span names; the base
+        # class's `sample_support_block` is the per-task loop
+        block_call = (policy_sampler == "vectorized"
+                      and type(task_dist).sample_support_block
+                      is not TaskDistribution.sample_support_block)
+        sample_path = ("pool" if pooled else
+                       "vectorized" if block_call else "reference")
         blocks, pad = plan_blocks(rounds, eval_every, max_block,
                                   start=start_round,
                                   ckpt_every=ckpt_every if ckpt_dir else 0)
@@ -1752,7 +1780,8 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
                                                   clients_per_round, budget)
                     cohort = None
                 part = np.asarray(plan["participation"], bool)
-            with TraceAnnotation("engine.sample", block=i, rounds=blk):
+            with TraceAnnotation("engine.sample", block=i, rounds=blk,
+                                 sampler=sample_path):
                 if pooled:
                     batch = pool.sample_cohort_block(cohort, part, support,
                                                      strategy.data_mode)

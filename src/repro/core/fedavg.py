@@ -24,7 +24,7 @@ def fedavg_train(loss_fn: Callable, init_params,
                  eval_every: int = 0,
                  eval_kwargs: Optional[dict] = None,
                  channel: Optional[CommChannel] = None,
-                 prefetch: int = 2, sampler: str = "reference",
+                 prefetch: int = 2, sampler: Optional[str] = None,
                  max_block: int = 512,
                  sampling: Optional[SamplingPolicy] = None,
                  pool: Optional[ClientPool] = None,
@@ -48,7 +48,7 @@ def fedsgd_train(loss_fn: Callable, init_params,
                  eval_every: int = 0,
                  eval_kwargs: Optional[dict] = None,
                  channel: Optional[CommChannel] = None,
-                 prefetch: int = 2, sampler: str = "reference",
+                 prefetch: int = 2, sampler: Optional[str] = None,
                  max_block: int = 512,
                  sampling: Optional[SamplingPolicy] = None,
                  pool: Optional[ClientPool] = None,

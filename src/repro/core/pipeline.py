@@ -414,12 +414,13 @@ class UniformSampling(SamplingPolicy):
     scan body, so runs are bit-for-bit identical to the pre-schedule
     engine.
 
-    sampler="reference" replays the legacy per-task RNG order bit-for-bit
-    (seeded parity with the pre-engine loops); "vectorized" uses the
-    distribution's batched ``sample_support_block`` (block RNG order, one
-    allocation — the fast host path).
+    sampler="vectorized" (the default, as for ``sampling=None``) takes
+    each block from the distribution's batched ``sample_support_block``
+    (block RNG order, one allocation); "reference" replays the legacy
+    per-task RNG order bit-for-bit (seeded parity with the pre-engine
+    loops).
     """
-    sampler: str = "reference"
+    sampler: str = "vectorized"
 
     schedule_kind = "uniform"
 

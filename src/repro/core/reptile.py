@@ -27,7 +27,7 @@ def reptile_train(loss_fn: Callable, init_params,
                   seed: int = 0, eval_every: int = 0,
                   eval_kwargs: Optional[dict] = None,
                   channel: Optional[CommChannel] = None,
-                  prefetch: int = 2, sampler: str = "reference",
+                  prefetch: int = 2, sampler: Optional[str] = None,
                   max_block: int = 512,
                   sampling: Optional[SamplingPolicy] = None,
                   pool: Optional[ClientPool] = None,

@@ -32,7 +32,7 @@ def tifed_train(init_params, task_dist: TaskDistribution, *,
                 anneal: bool = True, seed: int = 0, eval_every: int = 0,
                 eval_kwargs: Optional[dict] = None,
                 channel: Optional[CommChannel] = None,
-                prefetch: int = 2, sampler: str = "reference",
+                prefetch: int = 2, sampler: Optional[str] = None,
                 max_block: int = 512,
                 sampling: Optional[SamplingPolicy] = None,
                 pool: Optional[ClientPool] = None,

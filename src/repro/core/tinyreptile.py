@@ -35,7 +35,7 @@ def tinyreptile_train(loss_fn: Callable, init_params,
                       eval_every: int = 0, eval_kwargs: Optional[dict] = None,
                       use_pallas: Optional[bool] = None,
                       channel: Optional[CommChannel] = None,
-                      prefetch: int = 2, sampler: str = "reference",
+                      prefetch: int = 2, sampler: Optional[str] = None,
                       max_block: int = 512,
                       clients_per_round: int = 1,
                       sampling: Optional[SamplingPolicy] = None,

@@ -22,7 +22,7 @@ def transfer_train(loss_fn: Callable, init_params,
                    batch_per_round: int = 32, tasks_per_round: int = 8,
                    seed: int = 0, eval_every: int = 0,
                    eval_kwargs: Optional[dict] = None,
-                   prefetch: int = 2, sampler: str = "reference",
+                   prefetch: int = 2, sampler: Optional[str] = None,
                    max_block: int = 512,
                    sampling: Optional[SamplingPolicy] = None,
                    pool: Optional[ClientPool] = None,

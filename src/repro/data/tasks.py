@@ -18,16 +18,17 @@ Block sampling (the round engine's host path) comes in two flavours:
 - ``sample_support_block_reference``: a per-task Python loop consuming
   the RNG in exactly the order the legacy per-round loops did (task
   parameters interleaved with that task's support draws). This is the
-  seeded-parity anchor — the engine's default, and what every
-  vectorized override is validated against in spirit.
+  seeded-parity anchor (the engine's ``sampler="reference"``), and what
+  every vectorized override is validated against in spirit.
 - ``sample_support_block``: batched vectorized sampling — one NumPy
   allocation for the whole ``rounds x clients`` block, no per-sample
-  ``np.stack``. Overrides consume the RNG in a documented BLOCK order
-  (all task-level draws first, then each per-sample quantity as one
-  array draw), so a given seed yields different — but identically
-  distributed — tasks than the reference order. Within one sampler the
-  stream is deterministic, which is what the engine's prefetch pipeline
-  relies on for bit-for-bit pipelined-vs-synchronous parity.
+  ``np.stack``; the engine's default path. Overrides consume the RNG
+  in a documented BLOCK order (all task-level draws first, then each
+  per-sample quantity as one array draw), so a given seed yields
+  different — but identically distributed — tasks than the reference
+  order. Within one sampler the stream is deterministic, which is what
+  the engine's prefetch pipeline relies on for bit-for-bit
+  pipelined-vs-synchronous parity.
 """
 from __future__ import annotations
 

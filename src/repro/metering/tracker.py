@@ -24,7 +24,9 @@ sample or request.
   (the block's rounds), which pair the prefetch thread's spans with the
   consumer's:
   ``engine.plan`` (the sampling policy's schedule), ``engine.sample``
-  (its client data), ``engine.stage`` (padding, the ``ClientSchedule``
+  (its client data; ``sampler``: "vectorized" for one block call of
+  the distribution, "reference" for the per-task loop, "pool" for a
+  pooled run's check-ins), ``engine.stage`` (padding, the ``ClientSchedule``
   and the device transfer; ``bytes`` staged; a host-resident pool's
   identity window is a second one, on the consumer), ``engine.wait``
   (the consumer waiting for the block; with no prefetch thread it holds

@@ -1,6 +1,7 @@
 """Engine refactor parity: the strategy-backed ``*_train`` entry points
 must reproduce the pre-engine per-round Python loops — same seed, same
-history, same final params (tolerance <= 1e-5).
+history, same final params (tolerance <= 1e-5) — when they sample client
+data in the loops' per-task order (``sampler="reference"``).
 
 The legacy loops live HERE as fixtures (verbatim from the seed
 implementations, evals included), not in src/: the engine is the only
@@ -27,6 +28,9 @@ from repro.models.paper_nets import (init_paper_model, paper_model_loss,
 
 LOSS = functools.partial(paper_model_loss, SINE_MLP)
 EVAL = dict(num_tasks=4, support=8, k_steps=4, lr=0.02, query=16)
+# the legacy loops draw each task, then its samples: the engine's
+# per-task "reference" sampler, not its default block order
+REF = dict(sampler="reference")
 
 
 # ---------------------------------------------------------------------------
@@ -208,7 +212,7 @@ def test_tinyreptile_parity(setup):
     params, dist = setup
     kw = dict(rounds=60, alpha=1.0, beta=0.02, support=8, seed=11,
               eval_every=20, eval_kwargs=EVAL)
-    _assert_parity(tinyreptile_train(LOSS, params, dist, **kw),
+    _assert_parity(tinyreptile_train(LOSS, params, dist, **REF, **kw),
                    _legacy_tinyreptile(LOSS, params, dist, **kw))
 
 
@@ -216,7 +220,7 @@ def test_tinyreptile_no_anneal_no_eval_parity(setup):
     params, dist = setup
     kw = dict(rounds=25, alpha=0.7, beta=0.02, support=8, seed=12,
               anneal=False)
-    _assert_parity(tinyreptile_train(LOSS, params, dist, **kw),
+    _assert_parity(tinyreptile_train(LOSS, params, dist, **REF, **kw),
                    _legacy_tinyreptile(LOSS, params, dist, **kw))
 
 
@@ -224,7 +228,7 @@ def test_reptile_serial_parity(setup):
     params, dist = setup
     kw = dict(rounds=40, alpha=1.0, beta=0.02, support=8, epochs=4,
               clients_per_round=1, seed=13, eval_every=20, eval_kwargs=EVAL)
-    _assert_parity(reptile_train(LOSS, params, dist, **kw),
+    _assert_parity(reptile_train(LOSS, params, dist, **REF, **kw),
                    _legacy_reptile(LOSS, params, dist, **kw))
 
 
@@ -232,7 +236,7 @@ def test_reptile_batched_parity(setup):
     params, dist = setup
     kw = dict(rounds=30, alpha=1.0, beta=0.02, support=8, epochs=4,
               clients_per_round=3, seed=14, eval_every=15, eval_kwargs=EVAL)
-    _assert_parity(reptile_train(LOSS, params, dist, **kw),
+    _assert_parity(reptile_train(LOSS, params, dist, **REF, **kw),
                    _legacy_reptile(LOSS, params, dist, **kw))
 
 
@@ -240,7 +244,7 @@ def test_fedavg_parity(setup):
     params, dist = setup
     kw = dict(rounds=20, beta=0.02, support=8, epochs=4,
               clients_per_round=3, seed=15, eval_every=10, eval_kwargs=EVAL)
-    _assert_parity(fedavg_train(LOSS, params, dist, **kw),
+    _assert_parity(fedavg_train(LOSS, params, dist, **REF, **kw),
                    _legacy_fedavg(LOSS, params, dist, **kw))
 
 
@@ -248,7 +252,7 @@ def test_fedsgd_parity(setup):
     params, dist = setup
     kw = dict(rounds=30, beta=0.02, support=8, clients_per_round=3,
               seed=16, eval_every=15, eval_kwargs=EVAL)
-    _assert_parity(fedsgd_train(LOSS, params, dist, **kw),
+    _assert_parity(fedsgd_train(LOSS, params, dist, **REF, **kw),
                    _legacy_fedsgd(LOSS, params, dist, **kw))
 
 
@@ -256,7 +260,7 @@ def test_transfer_parity(setup):
     params, dist = setup
     kw = dict(rounds=40, beta=0.02, batch_per_round=24, tasks_per_round=6,
               seed=17, eval_every=20, eval_kwargs=EVAL)
-    got = transfer_train(LOSS, params, dist, **kw)
+    got = transfer_train(LOSS, params, dist, **REF, **kw)
     want = _legacy_transfer(LOSS, params, dist, **kw)
     assert "comm_bytes" not in got and "comm_bytes" not in want
     _assert_parity(got, want, check_comm=False)

@@ -7,6 +7,8 @@ Covers the four tentpole contracts:
   synchronous runs across eval cadences and uneven max_block tails;
 - vectorized block sampling == the scalar block-order reference loop for
   the sine distribution, and shape/dtype contracts for all distributions;
+- the default run's one block call a block, the pool's sampler for a
+  pooled run, and ``sampler=`` refused beside a policy;
 - TinyMetaFed-style partial-communication channel (fraction accounting +
   masked uplink) and the block-runner cache counters.
 """
@@ -19,15 +21,17 @@ import numpy as np
 import pytest
 
 from repro.configs.paper_models import SINE_MLP
-from repro.core import (CommChannel, PartialCommChannel, UniformSampling,
-                        clear_runner_cache, fedsgd_train, reptile_train,
-                        runner_cache_stats, tinyreptile_train)
+from repro.core import (ClientPool, CommChannel, PartialCommChannel,
+                        UniformSampling, clear_runner_cache, fedsgd_train,
+                        reptile_train, run_federated, runner_cache_stats,
+                        tinyreptile_train)
 from repro.core.engine import _block_runner
 from repro.core.meta import tree_bytes
 from repro.core.pipeline import BlockPrefetcher, plan_blocks
 from repro.core.strategies import TinyReptileStrategy
 from repro.data import SineTasks
-from repro.data.tasks import KWSTasks, OmniglotTasks
+from repro.data.tasks import (ClientTask, KWSTasks, OmniglotTasks,
+                              TaskDistribution)
 from repro.models.paper_nets import init_paper_model, paper_model_loss
 
 LOSS = functools.partial(paper_model_loss, SINE_MLP)
@@ -117,6 +121,84 @@ def test_sampling_policy_object_param(setup):
     _assert_trees_equal(a["params"], b["params"])
     with pytest.raises(ValueError):
         UniformSampling("bogus")
+
+
+class _CallData(TaskDistribution):
+    """A run's client data made in bulk up front and handed out in the
+    engine's order, as the chip benchmark's ``CallTasks`` does: task by task
+    and sample by sample to the per-task loop, whole rounds to the block
+    path. Client c of round r gets the same samples either way. Counts
+    both kinds of call."""
+
+    def __init__(self, rounds, clients, support):
+        self.clients, self.tasks, self.blocks = clients, 0, 0
+        self.data = SineTasks().sample_support_block(
+            np.random.default_rng(7), rounds, clients, support)
+
+    def sample_task(self, rng):
+        r, c = divmod(self.tasks, self.clients)
+        self.tasks += 1
+        samples = zip(self.data["x"][r, c], self.data["y"][r, c])
+        return ClientTask(make_sample=lambda _rng: next(samples),
+                          task_id=self.tasks - 1)
+
+    def sample_support_block(self, rng, rounds, clients, support,
+                             data_mode="batch", participation=None):
+        r0 = self.blocks * rounds
+        self.blocks += 1
+        return self._mask_block({k: v[r0:r0 + rounds].copy()
+                                 for k, v in self.data.items()},
+                                participation)
+
+
+def test_default_run_takes_one_block_call_a_block(setup):
+    """Naming no sampler, a run asks the distribution for each block's
+    data in one call and never for a task; on data served alike to both
+    routes it computes the per-task loop's params bit for bit."""
+    params, _ = setup
+    kw = dict(rounds=12, clients_per_round=3, alpha=1.0, beta=0.02,
+              support=4, seed=5, max_block=4)
+    block_dist, loop_dist = _CallData(12, 3, 4), _CallData(12, 3, 4)
+    block = run_federated(params, block_dist, TinyReptileStrategy(LOSS),
+                          **kw)
+    loop = run_federated(params, loop_dist, TinyReptileStrategy(LOSS),
+                         sampler="reference", **kw)
+    assert (block_dist.blocks, block_dist.tasks) == (3, 0)
+    assert (loop_dist.blocks, loop_dist.tasks) == (0, 12 * 3)
+    _assert_trees_equal(block["params"], loop["params"])
+
+
+@pytest.mark.parametrize("pool_sampler", ["reference", "vectorized"])
+def test_pooled_run_follows_the_pool_sampler(setup, pool_sampler):
+    """With a pool and no sampler, the run seats cohorts by the pool's
+    sampler: the same run as naming it, another than naming the other
+    (the pool is large enough beside the cohort for the two seatings to
+    differ)."""
+    params, dist = setup
+    other = {"reference": "vectorized", "vectorized": "reference"}
+    kw = dict(rounds=6, clients_per_round=3, beta=0.02, support=4, seed=4)
+
+    def run(**extra):
+        pool = ClientPool(dist, 64, seed=1, sampler=pool_sampler)
+        return tinyreptile_train(LOSS, params, dist, pool=pool, **extra,
+                                 **kw)["pool_state"]["checkins"]
+    default = run()
+    np.testing.assert_array_equal(default, run(sampler=pool_sampler))
+    assert not np.array_equal(default, run(sampler=other[pool_sampler]))
+
+
+@pytest.mark.parametrize("sampler", [None, "vectorized", "reference"])
+def test_sampler_beside_a_policy_is_refused(setup, sampler):
+    """A policy carries its own sampler: ``sampler=`` beside it is
+    refused whatever it names, and leaving it unset is not."""
+    params, dist = setup
+    kw = dict(rounds=2, support=4, sampler=sampler,
+              sampling=UniformSampling())
+    if sampler is None:
+        tinyreptile_train(LOSS, params, dist, **kw)
+    else:
+        with pytest.raises(ValueError, match="sampling policy"):
+            tinyreptile_train(LOSS, params, dist, **kw)
 
 
 # ---------------------------------------------------------------------------

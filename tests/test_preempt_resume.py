@@ -15,7 +15,9 @@ an arbitrary execution point and falling back to the newest durable
 snapshot.
 """
 import functools
+import json
 import os
+import shutil
 import subprocess
 import sys
 import tempfile
@@ -298,6 +300,56 @@ def test_resume_fingerprint_mismatch_rejected(sine_setup):
         with pytest.raises(ValueError, match="different run config"):
             run_federated(params, dist, strategy, seed=99, ckpt_dir=d,
                           ckpt_every=2, resume=True, **kw)
+
+
+def test_resume_under_another_sampler_rejected(sine_setup):
+    """The fingerprint records the sampler the run resolved: a snapshot
+    of the per-task sampler (the default before the block path) is
+    refused under another named sampler, and a run that names none
+    continues it under its own, bit for bit."""
+    params, dist, strategy = sine_setup
+    kw = dict(beta=0.02, support=6, seed=5, anneal=False)
+    with tempfile.TemporaryDirectory() as d:
+        run_federated(params, dist, strategy, rounds=2, ckpt_dir=d,
+                      ckpt_every=2, ckpt_async=False, sampler="reference",
+                      **kw)
+        with pytest.raises(ValueError, match="policy_sampler"):
+            run_federated(params, dist, strategy, rounds=4, ckpt_dir=d,
+                          ckpt_every=2, resume=True, sampler="vectorized",
+                          **kw)
+        resumed = run_federated(params, dist, strategy, rounds=4,
+                                ckpt_dir=d, ckpt_every=2, resume=True, **kw)
+        fresh = run_federated(params, dist, strategy, rounds=4,
+                              sampler="reference", **kw)
+        _exact(fresh["params"], resumed["params"])
+
+
+def test_cli_resumes_a_snapshot_of_the_per_task_default(tmp_path,
+                                                        monkeypatch, capsys):
+    """A launcher run snapshotted under the per-task sampler (the default
+    before the block path) continues from the CLI, which names no
+    sampler, in that order: as a resume that names the sampler does."""
+    import repro.core
+    from repro.launch import train
+
+    def cli(ckpt_dir, *argv):
+        train.run_engine_strategy(train.parse_args(
+            ["--strategy", "reptile", "--clients", "2", "--ckpt-dir",
+             str(ckpt_dir), "--ckpt-every", "2", *argv]))
+        row = json.loads(capsys.readouterr().out.splitlines()[-1])
+        row.pop("dt_s")
+        return row
+
+    per_task = functools.partial(run_federated, sampler="reference")
+    old, named = tmp_path / "old", tmp_path / "named"
+    with monkeypatch.context() as m:
+        m.setattr(repro.core, "run_federated", per_task)
+        cli(old, "--rounds", "2")
+        shutil.copytree(old, named)
+        want = cli(named, "--rounds", "4", "--resume")
+    got = cli(old, "--rounds", "4", "--resume")
+    assert (old / "ckpt_00000004.npz").exists()
+    assert got == want
 
 
 def test_resume_mesh_layout_mismatch_rejected(sine_setup):

@@ -4,6 +4,7 @@ profiler session records them.
 - ``run_federated`` records ``engine.run`` and each block's phases; the
   prefetch thread's spans pair with the consumer's by ``block``, and
   with ``prefetch=0`` every span sits on the calling thread.
+  ``engine.sample`` names the sampler its block's data came through.
 - The server's ``serve.tick`` counts add up to the stream: admitted and
   retired to the requests, ``live`` to the steps they asked for, and the
   admitted id ranges follow one another; each tick holds its four
@@ -20,9 +21,10 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.configs.paper_models import SINE_MLP
-from repro.core import run_federated
+from repro.core import ClientPool, run_federated
 from repro.core.strategies import TinyReptileStrategy
 from repro.data import SineTasks
+from repro.data.tasks import TaskDistribution
 from repro.models.paper_nets import init_paper_model, paper_model_loss
 from repro.serving import AdaptationServer, Fp32Adapter
 
@@ -45,11 +47,19 @@ def _spans(trace_dir):
             if ev.name.startswith(("engine.", "serve."))]
 
 
-def _train(prefetch):
+class _PerTaskSine(TaskDistribution):
+    """Sine tasks with no block call of their own: the base class's
+    ``sample_support_block`` runs the per-task loop."""
+
+    def sample_task(self, rng):
+        return SineTasks().sample_task(rng)
+
+
+def _train(prefetch, dist=None, **kw):
     phi = init_paper_model(SINE_MLP, jax.random.PRNGKey(0))
-    return run_federated(phi, SineTasks(), TinyReptileStrategy(LOSS),
+    return run_federated(phi, dist or SineTasks(), TinyReptileStrategy(LOSS),
                          rounds=8, clients_per_round=2, support=4, seed=3,
-                         max_block=4, prefetch=prefetch)["params"]
+                         max_block=4, prefetch=prefetch, **kw)["params"]
 
 
 def _serve():
@@ -70,6 +80,26 @@ def _serve():
     results = server.step() + server.step()
     submit(5, 9)
     return server, ks, results + server.drain()
+
+
+@pytest.mark.parametrize("kw,sampler", [
+    ({}, "vectorized"),
+    ({"sampler": "reference"}, "reference"),
+    ({"pool": ClientPool(SineTasks(), 4)}, "pool"),
+    ({"dist": _PerTaskSine()}, "reference"),
+])
+def test_engine_sample_names_its_sampler(tmp_path, kw, sampler):
+    """Each block's ``engine.sample`` names the path its data took: one
+    block call by default, the per-task loop when named or when the
+    distribution has no block call of its own, a pool's check-ins when
+    pooled."""
+    _train(2, **kw)                            # compiled outside the trace
+    with jax.profiler.trace(str(tmp_path)):
+        _train(2, **kw)
+    found = [s["args"] for s in _spans(tmp_path)
+             if s["name"] == "engine.sample"]
+    assert [a["block"] for a in found] == [0, 1]
+    assert all(a["sampler"] == sampler for a in found), found
 
 
 @pytest.mark.parametrize("prefetch", [0, 2])
