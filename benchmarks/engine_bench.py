@@ -265,8 +265,7 @@ def mesh_scaling(rounds: int = ROUNDS, smoke: bool = False):
                 out = tinyreptile_train(
                     MESH_LOSS, params, dist, rounds=rounds, alpha=1.0,
                     beta=beta, support=MESH_SUPPORT, seed=0,
-                    clients_per_round=cohort, sampler="vectorized",
-                    mesh=mesh, **pipe)
+                    clients_per_round=cohort, mesh=mesh, **pipe)
                 jax.block_until_ready(jax.tree.leaves(out["params"])[0])
             rps = _rounds_per_sec(run, rounds)
             row = {"rounds_per_sec": round(rps, 2)}
@@ -507,9 +506,8 @@ def bench(rounds: int = ROUNDS, smoke: bool = False):
     # with device compute on block N (one monolithic block would
     # degenerate to inline staging with nothing to overlap) — also at
     # smoke round counts.
-    sync = dict(prefetch=0, sampler="reference")
-    piped = dict(prefetch=2, sampler="vectorized",
-                 max_block=min(16, max(1, rounds // 4)))
+    sync = dict(prefetch=0, sampling=UniformSampling("reference"))
+    piped = dict(prefetch=2, max_block=min(16, max(1, rounds // 4)))
 
     cases = [
         ("tinyreptile",
@@ -593,22 +591,17 @@ def bench(rounds: int = ROUNDS, smoke: bool = False):
 
     # -- heterogeneity: the ClientSchedule layer on the batched cohort --
     cohorts = [
-        ("full_participation", UniformSampling("vectorized")),
-        ("partial_participation_50", PartialParticipation(
-            0.5, sampler="vectorized")),
-        ("straggler_cohort_25", StragglerSampling(
-            0.25, sampler="vectorized")),
+        ("full_participation", UniformSampling()),
+        ("partial_participation_50", PartialParticipation(0.5)),
+        ("straggler_cohort_25", StragglerSampling(0.25)),
     ]
     het = {}
-    # the policies carry their own sampler; pass only the pipeline knobs
-    # (run_federated rejects a non-default sampler= next to sampling=)
-    pipe_kw = {k: piped[k] for k in ("prefetch", "max_block")}
     for name, policy in cohorts:
         def run_policy(policy=policy):
             out = reptile_train(LOSS, params, dist, rounds=rounds,
                                 alpha=1.0, beta=0.02, support=SUPPORT,
                                 epochs=8, clients_per_round=8, seed=0,
-                                sampling=policy, **pipe_kw)
+                                sampling=policy, **piped)
             jax.block_until_ready(jax.tree.leaves(out["params"])[0])
             return out
         out = run_policy()            # doubles as warmup + accounting
@@ -638,13 +631,14 @@ def bench(rounds: int = ROUNDS, smoke: bool = False):
     # the pool samples each check-in from that client's private stream).
     POOL_N = 32
     fedbuff = BufferedAggregation(16)
+    per_task = UniformSampling("reference")
     pool_cases = [
-        ("legacy_uniform", dict(sampling=UniformSampling("reference")),
-         None),
-        ("pooled_uniform", dict(), None),
-        ("pooled_diurnal", dict(sampling=DiurnalAvailability(period=24)),
-         None),
-        ("pooled_fedbuff_k16", dict(buffered=fedbuff), fedbuff),
+        ("legacy_uniform", dict(sampling=per_task), None),
+        ("pooled_uniform", dict(sampling=per_task), None),
+        ("pooled_diurnal", dict(sampling=DiurnalAvailability(
+            period=24, sampler="reference")), None),
+        ("pooled_fedbuff_k16", dict(sampling=per_task, buffered=fedbuff),
+         fedbuff),
     ]
     pool_sec = {}
     for name, case_kw, buffered in pool_cases:
@@ -653,11 +647,12 @@ def bench(rounds: int = ROUNDS, smoke: bool = False):
         def run_case(case_kw=case_kw, pooled_case=pooled_case):
             kw = dict(case_kw)
             if pooled_case:
-                kw["pool"] = ClientPool(dist, POOL_N, seed=0)
+                kw["pool"] = ClientPool(dist, POOL_N, seed=0,
+                                        sampler="reference")
             out = reptile_train(LOSS, params, dist, rounds=rounds,
                                 alpha=1.0, beta=0.02, support=SUPPORT,
                                 epochs=8, clients_per_round=8, seed=0,
-                                **pipe_kw, **kw)
+                                **piped, **kw)
             jax.block_until_ready(jax.tree.leaves(out["params"])[0])
             return out
         out = run_case()              # doubles as warmup + pool state
@@ -700,8 +695,7 @@ def bench(rounds: int = ROUNDS, smoke: bool = False):
     scale_sec = {"cohort": 256, "rounds": scale_rounds}
     scale_rps = {}
     for n in (256, 10_000, 1_000_000):
-        pool = ClientPool(dist, n, seed=0, sampler="vectorized",
-                          residency="host")
+        pool = ClientPool(dist, n, seed=0, residency="host")
         meter = MemoryMeter()
 
         def run_scale(pool=pool):
@@ -709,7 +703,7 @@ def bench(rounds: int = ROUNDS, smoke: bool = False):
                       TinyReptileStrategy(LOSS, use_pallas=None),
                       rounds=scale_rounds, clients_per_round=256,
                       alpha=1.0, beta=0.02, support=8, seed=0,
-                      **pipe_kw, pool=pool)
+                      **piped, pool=pool)
             jax.block_until_ready(jax.tree.leaves(out["params"])[0])
 
         rps = _rounds_per_sec(run_scale, scale_rounds,
@@ -760,8 +754,7 @@ def bench(rounds: int = ROUNDS, smoke: bool = False):
         out = _run_federated(
             ck_params, dist, _Reptile(MESH_LOSS, epochs=8, use_pallas=None),
             rounds=rounds, alpha=1.0, beta=0.02, support=MESH_SUPPORT,
-            clients_per_round=8, seed=0, prefetch=2, max_block=16,
-            sampling=UniformSampling("vectorized"), **kw)
+            clients_per_round=8, seed=0, prefetch=2, max_block=16, **kw)
         jax.block_until_ready(jax.tree.leaves(out["params"])[0])
 
     with _tempfile.TemporaryDirectory() as ckpt_d:

@@ -1336,7 +1336,6 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
                   eval_kwargs: Optional[dict] = None,
                   channel: Optional[CommChannel] = None,
                   max_block: int = 512, prefetch: int = 2,
-                  sampler: Optional[str] = None,
                   sampling: Optional[SamplingPolicy] = None,
                   pool: Optional[ClientPool] = None,
                   buffered: Optional[BufferedAggregation] = None,
@@ -1367,20 +1366,19 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
     (double-buffered at the default 2); `prefetch=0` is the synchronous
     escape hatch — both are bit-for-bit identical.
 
-    `sampler` names the default uniform policy's data path. Left unset,
-    a pool-free run takes each block's client data from
-    `task_dist.sample_support_block` in one call (a distribution that
-    does not override it falls back to the per-task loop), and a pooled
-    run follows `pool.sampler`. The built-in distributions draw that
-    block in block RNG order, so a seeded run gives different (but
-    identically distributed) tasks than the legacy per-round loops;
-    `sampler="reference"` replays those loops' per-task order
-    bit-for-bit. The checkpoint fingerprint records the sampler: a run
-    that names neither `sampler=` nor `sampling=` resumes a snapshot
-    under the sampler it recorded (one written under the old "reference"
-    default continues in that order), and a named one must match it.
-    With an explicit `sampling=` policy the sampler is the policy's own,
-    and `sampler=` must stay unset.
+    The host data path is the `sampling` policy's `sampler`; with no
+    policy the run uses `UniformSampling()`. Every policy's default
+    (repro.core.pipeline.DEFAULT_SAMPLER) takes each block's client data
+    from `task_dist.sample_support_block` in one call (a distribution
+    that does not override it falls back to the per-task loop). The
+    built-in distributions draw that block in block RNG order, so a
+    seeded run gives different (but identically distributed) tasks than
+    the legacy per-round loops; `sampling=UniformSampling("reference")`
+    replays those loops' per-task order bit-for-bit. The checkpoint
+    fingerprint records the policy's sampler: a run with no `sampling=`
+    resumes a snapshot under the sampler it recorded (one written under
+    the old "reference" default continues in that order), and a named
+    policy must match it.
 
     `pool` switches the run onto PERSISTENT client identities (a
     repro.core.pool.ClientPool over `task_dist`): each round the policy
@@ -1394,7 +1392,8 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
     (per_client_bytes has pool.size entries) and return a "pool_state"
     dict (last_seen / staleness / checkins arrays [+ flushes,
     buffered_pending]); `pool=None` keeps the legacy anonymous-cohort
-    path bit-for-bit.
+    path bit-for-bit. The policy only seats a pooled run's cohort; the
+    pool draws the data through its own `sampler`.
 
     `mesh` SHARDS THE CLIENT AXIS across devices: pass a 1-D "clients"
     Mesh (see `client_mesh`), an int (first n devices), or "auto"
@@ -1445,26 +1444,13 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
                          seed=seed) as run_span:
         if channel is None:
             channel = CommChannel()
-        # a run that names neither continues a snapshot under the sampler
-        # the snapshot recorded (see the resume below)
-        sampler_named = sampler is not None or sampling is not None
+        # a run with no policy continues a snapshot under the sampler the
+        # snapshot recorded (see the resume below)
+        policy_named = sampling is not None
         if sampling is None:
-            # a pooled run's host-path contract is the POOL's sampler: a
-            # vectorized (fleet-scale) pool must also seat cohorts through
-            # the O(cohort) block path, not the per-round O(N) choice loop
-            if sampler is None:
-                sampler = pool.sampler if pool is not None else "vectorized"
-            sampling = UniformSampling(sampler)
-        elif sampler is not None:
-            # an explicit policy owns its own sampler choice; silently
-            # ignoring a non-default `sampler=` string would run a different
-            # host path than the caller asked for
-            raise ValueError(
-                f"pass the sampler on the sampling policy (e.g. "
-                f"{type(sampling).__name__}(..., sampler={sampler!r})), not "
-                f"as run_federated(sampler=...) alongside sampling=")
+            sampling = UniformSampling()
         pooled = pool is not None
-        policy_sampler = getattr(sampling, "sampler", "reference")
+        policy_sampler = sampling.sampler
         if buffered is not None:
             if not pooled:
                 raise ValueError("buffered aggregation needs persistent "
@@ -1639,7 +1625,7 @@ def run_federated(init_params, task_dist: TaskDistribution, strategy, *,
             if saved is not None:
                 # e.g. a snapshot written when "reference" was the default
                 was = (saved.fingerprint or {}).get("policy_sampler")
-                if not sampler_named and was in SAMPLERS:
+                if not policy_named and was in SAMPLERS:
                     policy_sampler = fingerprint["policy_sampler"] = was
                     sampling = UniformSampling(was)
                 diff = {k: (saved.fingerprint.get(k), v)

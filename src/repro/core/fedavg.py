@@ -24,8 +24,7 @@ def fedavg_train(loss_fn: Callable, init_params,
                  eval_every: int = 0,
                  eval_kwargs: Optional[dict] = None,
                  channel: Optional[CommChannel] = None,
-                 prefetch: int = 2, sampler: Optional[str] = None,
-                 max_block: int = 512,
+                 prefetch: int = 2, max_block: int = 512,
                  sampling: Optional[SamplingPolicy] = None,
                  pool: Optional[ClientPool] = None,
                  buffered: Optional[BufferedAggregation] = None,
@@ -37,7 +36,7 @@ def fedavg_train(loss_fn: Callable, init_params,
         rounds=rounds, clients_per_round=clients_per_round, alpha=1.0,
         beta=beta, support=support, anneal=False, seed=seed,
         eval_every=eval_every, eval_kwargs=eval_kwargs, channel=channel,
-        prefetch=prefetch, sampler=sampler, max_block=max_block,
+        prefetch=prefetch, max_block=max_block,
         sampling=sampling, pool=pool, buffered=buffered, mesh=mesh)
 
 
@@ -48,8 +47,7 @@ def fedsgd_train(loss_fn: Callable, init_params,
                  eval_every: int = 0,
                  eval_kwargs: Optional[dict] = None,
                  channel: Optional[CommChannel] = None,
-                 prefetch: int = 2, sampler: Optional[str] = None,
-                 max_block: int = 512,
+                 prefetch: int = 2, max_block: int = 512,
                  sampling: Optional[SamplingPolicy] = None,
                  pool: Optional[ClientPool] = None,
                  buffered: Optional[BufferedAggregation] = None,
@@ -61,5 +59,5 @@ def fedsgd_train(loss_fn: Callable, init_params,
         rounds=rounds, clients_per_round=clients_per_round, alpha=1.0,
         beta=beta, support=support, anneal=False, seed=seed,
         eval_every=eval_every, eval_kwargs=eval_kwargs, channel=channel,
-        prefetch=prefetch, sampler=sampler, max_block=max_block,
+        prefetch=prefetch, max_block=max_block,
         sampling=sampling, pool=pool, buffered=buffered, mesh=mesh)

@@ -46,6 +46,17 @@ import jax
 import numpy as np
 
 SAMPLERS = ("reference", "vectorized")
+#: the host data path of every policy and ClientPool not told otherwise:
+#: one ``sample_support_block`` call a block. "reference" is the legacy
+#: per-task loop, kept as the seeded-parity oracle.
+DEFAULT_SAMPLER = "vectorized"
+
+
+def check_sampler(sampler: str) -> None:
+    """Raise unless ``sampler`` names a host data path."""
+    if sampler not in SAMPLERS:
+        raise ValueError(f"unknown sampler {sampler!r}; expected one of "
+                         f"{SAMPLERS}")
 
 
 def plan_blocks(rounds: int, eval_every: int, max_block: int, *,
@@ -228,9 +239,9 @@ def seat_cohorts(rng, pool_size: int, clients: int,
     sampling touches O(k) candidates per row (expected collisions
     ~k^2/2n, vanishing as n grows); near-dense rows (8k >= n) keep the
     permutation draw, which is optimal there. Consumes ``rng``
-    deterministically in row order — a NEW stream contract for the
-    ``sampler="vectorized"`` path (``sampler="reference"`` keeps the
-    legacy per-round ``choice`` order bit-for-bit)."""
+    deterministically in row order — the stream contract of the default
+    sampler (``sampler="reference"`` keeps the legacy per-round
+    ``choice`` order bit-for-bit)."""
     out = np.empty((rows, clients), np.int32)
     if clients * 8 >= pool_size:
         for r in range(rows):
@@ -319,7 +330,13 @@ class SamplingPolicy:
     """
 
     schedule_kind = "scheduled"
-    sampler = "reference"        # subclasses usually expose this as a field
+    #: the host data path ("vectorized" block call or the "reference"
+    #: per-task loop); dataclass subclasses expose it as a field and
+    #: validate it through ``__post_init__``
+    sampler = DEFAULT_SAMPLER
+
+    def __post_init__(self):
+        check_sampler(self.sampler)
 
     def plan_schedule(self, rng, start: int, end: int, clients: int,
                       budget: int) -> Dict[str, np.ndarray]:
@@ -399,11 +416,6 @@ class SamplingPolicy:
         run's (already-restored) host generator, for policies whose
         stashed state is keyed by the stream driving it."""
 
-    def _validate_sampler(self):
-        if self.sampler not in SAMPLERS:
-            raise ValueError(f"unknown sampler {self.sampler!r}; "
-                             f"expected one of {SAMPLERS}")
-
 
 @dataclasses.dataclass(frozen=True)
 class UniformSampling(SamplingPolicy):
@@ -414,18 +426,15 @@ class UniformSampling(SamplingPolicy):
     scan body, so runs are bit-for-bit identical to the pre-schedule
     engine.
 
-    sampler="vectorized" (the default, as for ``sampling=None``) takes
+    The default sampler (``DEFAULT_SAMPLER``, as for every policy) takes
     each block from the distribution's batched ``sample_support_block``
-    (block RNG order, one allocation); "reference" replays the legacy
-    per-task RNG order bit-for-bit (seeded parity with the pre-engine
-    loops).
+    (block RNG order, one allocation); ``UniformSampling("reference")``
+    replays the legacy per-task RNG order bit-for-bit (seeded parity
+    with the pre-engine loops).
     """
-    sampler: str = "vectorized"
+    sampler: str = DEFAULT_SAMPLER
 
     schedule_kind = "uniform"
-
-    def __post_init__(self):
-        self._validate_sampler()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -435,20 +444,20 @@ class PartialParticipation(SamplingPolicy):
     and pay transport; the server averages over exactly the participants
     (weights 1/m on participants, 0 elsewhere).
 
-    Scheduled-out slots draw NO task data from the host rng under the
-    "reference" sampler (their batch slots stay zero) — the host-side
-    sampling work scales with the fraction, which is where TinyMetaFed's
-    savings come from. The "vectorized" sampler samples the full block in
-    one allocation and zeroes the scheduled-out slots afterwards.
+    The default sampler samples the full block in one allocation and
+    zeroes the scheduled-out slots afterwards. Under
+    ``sampler="reference"`` scheduled-out slots draw NO task data from
+    the host rng — the host-side sampling work scales with the fraction,
+    which is where TinyMetaFed's savings come from.
     """
     fraction: float = 0.5
-    sampler: str = "reference"
+    sampler: str = DEFAULT_SAMPLER
 
     def __post_init__(self):
         if not 0.0 < self.fraction <= 1.0:
             raise ValueError(f"fraction must be in (0, 1], got "
                              f"{self.fraction!r}")
-        self._validate_sampler()
+        super().__post_init__()
 
     def cohort(self, clients: int) -> int:
         """Participants per round."""
@@ -481,13 +490,13 @@ class StragglerSampling(SamplingPolicy):
     so blocks stay fixed-shape and the runner still traces exactly once.
     """
     min_steps_frac: float = 0.25
-    sampler: str = "reference"
+    sampler: str = DEFAULT_SAMPLER
 
     def __post_init__(self):
         if not 0.0 < self.min_steps_frac <= 1.0:
             raise ValueError(f"min_steps_frac must be in (0, 1], got "
                              f"{self.min_steps_frac!r}")
-        self._validate_sampler()
+        super().__post_init__()
 
     def plan_schedule(self, rng, start, end, clients, budget):
         blk = end - start

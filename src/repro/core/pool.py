@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.pipeline import SAMPLERS, SamplingPolicy
+from repro.core.pipeline import DEFAULT_SAMPLER, SamplingPolicy, check_sampler
 from repro.data.tasks import TaskDistribution
 
 #: stream-key constants: keep a pool's task seeds, per-client data
@@ -193,16 +193,16 @@ class ClientPool:
 
     Two host-identity representations:
 
-    - ``sampler="reference"`` (default, legacy bit-for-bit): one cached
-      task object and one live ``np.random.Generator`` per client that
-      ever checked in — O(active clients) host objects, generators
-      never evictable (their stream state is irreplaceable).
-    - ``sampler="vectorized"``: NO per-client host objects. The pool
-      keeps ONE ``(N,)`` int32 check-in counter array; client ``i``'s
-      ``k``-th check-in draws from the counter-derived streams
-      ``default_rng([seed, _TASK_STREAM, i])`` (task params — the
-      ``materialize_client`` derivation) and ``default_rng([seed,
-      _DATA_STREAM, i, k])`` (data), routed through
+    - ``sampler="reference"`` (legacy bit-for-bit): one cached task
+      object and one live ``np.random.Generator`` per client that ever
+      checked in — O(active clients) host objects, generators never
+      evictable (their stream state is irreplaceable).
+    - ``sampler="vectorized"`` (``DEFAULT_SAMPLER``): NO per-client
+      host objects. The pool keeps ONE ``(N,)`` int32 check-in counter
+      array; client ``i``'s ``k``-th check-in draws from the
+      counter-derived streams ``default_rng([seed, _TASK_STREAM, i])``
+      (task params — the ``materialize_client`` derivation) and
+      ``default_rng([seed, _DATA_STREAM, i, k])`` (data), routed through
       ``TaskDistribution.sample_client_support``. Host memory is
       O(cohort) per round plus the counters; ``host_state()`` shrinks
       from a dict of bit-generator states to the nonzero counters.
@@ -223,15 +223,13 @@ class ClientPool:
     SLAB_FIELDS = ("last_seen", "staleness", "checkins")
 
     def __init__(self, task_dist: TaskDistribution, size: int,
-                 seed: int = 0, *, sampler: str = "reference",
+                 seed: int = 0, *, sampler: str = DEFAULT_SAMPLER,
                  residency: str = "device",
                  mmap_dir: Optional[str] = None,
                  max_cached_tasks: int = 4096):
         if size < 1:
             raise ValueError(f"pool size must be >= 1, got {size!r}")
-        if sampler not in SAMPLERS:
-            raise ValueError(f"unknown sampler {sampler!r}; expected "
-                             f"one of {SAMPLERS}")
+        check_sampler(sampler)
         if residency not in RESIDENCIES:
             raise ValueError(f"unknown residency {residency!r}; "
                              f"expected one of {RESIDENCIES}")
@@ -579,7 +577,7 @@ class AvailabilityProcess(SamplingPolicy):
     fixed-shape scan never retraces. These policies only make sense
     over a pool: ``plan_schedule`` (the anonymous-cohort hook) raises.
     """
-    sampler: str = "reference"
+    sampler: str = DEFAULT_SAMPLER
 
     schedule_kind = "scheduled"
 
@@ -677,7 +675,7 @@ class DiurnalAvailability(AvailabilityProcess):
             raise ValueError(f"phase_spread must be in [0, 1] (fraction "
                              f"of the fleet's phase fan-out), got "
                              f"{self.phase_spread!r}")
-        self._validate_sampler()
+        super().__post_init__()
 
     def availability(self, rng, start, end, pool_size):
         r = np.arange(start, end, dtype=np.float64)[:, None]
@@ -718,7 +716,7 @@ class MarkovAvailability(AvailabilityProcess):
             v = getattr(self, name)
             if not 0.0 < v <= 1.0:
                 raise ValueError(f"{name} must be in (0, 1], got {v!r}")
-        self._validate_sampler()
+        super().__post_init__()
 
     def availability(self, rng, start, end, pool_size):
         if start == 0:

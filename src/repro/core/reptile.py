@@ -27,8 +27,7 @@ def reptile_train(loss_fn: Callable, init_params,
                   seed: int = 0, eval_every: int = 0,
                   eval_kwargs: Optional[dict] = None,
                   channel: Optional[CommChannel] = None,
-                  prefetch: int = 2, sampler: Optional[str] = None,
-                  max_block: int = 512,
+                  prefetch: int = 2, max_block: int = 512,
                   sampling: Optional[SamplingPolicy] = None,
                   pool: Optional[ClientPool] = None,
                   buffered: Optional[BufferedAggregation] = None,
@@ -43,5 +42,5 @@ def reptile_train(loss_fn: Callable, init_params,
         rounds=rounds, clients_per_round=clients_per_round, alpha=alpha,
         beta=beta, support=support, anneal=anneal, seed=seed,
         eval_every=eval_every, eval_kwargs=eval_kwargs, channel=channel,
-        prefetch=prefetch, sampler=sampler, max_block=max_block,
+        prefetch=prefetch, max_block=max_block,
         sampling=sampling, pool=pool, buffered=buffered, mesh=mesh)

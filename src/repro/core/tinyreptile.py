@@ -35,24 +35,24 @@ def tinyreptile_train(loss_fn: Callable, init_params,
                       eval_every: int = 0, eval_kwargs: Optional[dict] = None,
                       use_pallas: Optional[bool] = None,
                       channel: Optional[CommChannel] = None,
-                      prefetch: int = 2, sampler: Optional[str] = None,
-                      max_block: int = 512,
+                      prefetch: int = 2, max_block: int = 512,
                       clients_per_round: int = 1,
                       sampling: Optional[SamplingPolicy] = None,
                       pool: Optional[ClientPool] = None,
                       buffered: Optional[BufferedAggregation] = None,
                       mesh=None) -> Dict:
     """Returns {"params", "history", "comm_bytes", "per_client_bytes"};
-    history rows are per-eval dicts. `prefetch`/`sampler`/`max_block`
-    tune the engine's host/device pipeline; `sampling` plugs in a
-    heterogeneity schedule (partial participation / stragglers) and
-    `clients_per_round` > 1 grows the paper's serial schema into a
-    cohort for such policies (see repro.core.engine.run_federated)."""
+    history rows are per-eval dicts. `prefetch`/`max_block` tune the
+    engine's host/device pipeline; `sampling` picks the host data path
+    and plugs in a heterogeneity schedule (partial participation /
+    stragglers) and `clients_per_round` > 1 grows the paper's serial
+    schema into a cohort for such policies (see
+    repro.core.engine.run_federated)."""
     return run_federated(
         init_params, task_dist,
         TinyReptileStrategy(loss_fn, use_pallas=use_pallas),
         rounds=rounds, clients_per_round=clients_per_round, alpha=alpha,
         beta=beta, support=support, anneal=anneal, seed=seed,
         eval_every=eval_every, eval_kwargs=eval_kwargs, channel=channel,
-        prefetch=prefetch, sampler=sampler, max_block=max_block,
+        prefetch=prefetch, max_block=max_block,
         sampling=sampling, pool=pool, buffered=buffered, mesh=mesh)

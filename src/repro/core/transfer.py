@@ -22,8 +22,7 @@ def transfer_train(loss_fn: Callable, init_params,
                    batch_per_round: int = 32, tasks_per_round: int = 8,
                    seed: int = 0, eval_every: int = 0,
                    eval_kwargs: Optional[dict] = None,
-                   prefetch: int = 2, sampler: Optional[str] = None,
-                   max_block: int = 512,
+                   prefetch: int = 2, max_block: int = 512,
                    sampling: Optional[SamplingPolicy] = None,
                    pool: Optional[ClientPool] = None,
                    mesh=None) -> Dict:
@@ -33,5 +32,4 @@ def transfer_train(loss_fn: Callable, init_params,
         rounds=rounds, clients_per_round=tasks_per_round, alpha=0.0,
         beta=beta, support=per_task, anneal=False, seed=seed,
         eval_every=eval_every, eval_kwargs=eval_kwargs, prefetch=prefetch,
-        sampler=sampler, max_block=max_block, sampling=sampling, pool=pool,
-        mesh=mesh)
+        max_block=max_block, sampling=sampling, pool=pool, mesh=mesh)

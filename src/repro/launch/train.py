@@ -50,7 +50,8 @@ import numpy as np
 from repro.checkpoint import restore_checkpoint, save_checkpoint
 from repro.configs import ALL_ARCHS, get_arch
 from repro.core.engine import CommChannel, meta_interpolate, streaming_sgd
-from repro.core.pipeline import PartialParticipation, single_device_of
+from repro.core.pipeline import (DEFAULT_SAMPLER, SAMPLERS,
+                                 PartialParticipation, single_device_of)
 from repro.core.pool import (DiurnalAvailability, MarkovAvailability,
                              default_staleness_weight)
 from repro.data import LMClientStream
@@ -157,14 +158,15 @@ def parse_args(argv=None):
                     help="size of the persistent client fleet (overrides "
                          "--clients; every client keeps its own data "
                          "stream across check-ins)")
-    ap.add_argument("--pool-sampler", default="reference",
-                    choices=("reference", "vectorized"),
-                    help="client-identity sampler for --pool-size: "
+    ap.add_argument("--pool-sampler", default=DEFAULT_SAMPLER,
+                    choices=SAMPLERS,
+                    help="host data path for --pool-size and its "
+                         "--availability/--participation policy: "
+                         "'vectorized' (default) derives each "
+                         "check-in from a counter array — O(cohort) "
+                         "host work and an O(N) int32 footprint; "
                          "'reference' keeps one RNG per client on the "
-                         "host (bit-for-bit legacy stream); "
-                         "'vectorized' derives each check-in from a "
-                         "counter array — O(cohort) host work and an "
-                         "O(N) int32 footprint, the fleet-scale mode")
+                         "host (bit-for-bit legacy stream)")
     ap.add_argument("--pool-residency", default="device",
                     choices=("device", "host"),
                     help="where --pool-size per-client state lives: "
@@ -312,7 +314,7 @@ def parse_args(argv=None):
     if args.availability != "iid" and args.pool_size is None:
         ap.error("--availability needs a persistent fleet on the engine "
                  "path: pass --pool-size N")
-    if args.pool_size is None and (args.pool_sampler != "reference"
+    if args.pool_size is None and (args.pool_sampler != DEFAULT_SAMPLER
                                    or args.pool_residency != "device"):
         ap.error("--pool-sampler/--pool-residency configure the "
                  "persistent fleet: pass --pool-size N")

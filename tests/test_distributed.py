@@ -177,7 +177,7 @@ for argv in (["--strategy", "reptile", "--num-processes", "2"],
               "--num-processes", "2", "--process-id", "2"],
              ["--strategy", "tinyreptile", "--arch", "gpt2-125m",
               "--coordinator", "h:1", "--num-processes", "2"],
-             ["--strategy", "reptile", "--pool-sampler", "vectorized"],
+             ["--strategy", "reptile", "--pool-sampler", "reference"],
              ["--strategy", "reptile", "--pool-residency", "host"]):
     try:
         parse_args(argv)

@@ -1,7 +1,7 @@
 """Engine refactor parity: the strategy-backed ``*_train`` entry points
 must reproduce the pre-engine per-round Python loops — same seed, same
 history, same final params (tolerance <= 1e-5) — when they sample client
-data in the loops' per-task order (``sampler="reference"``).
+data in the loops' per-task order (``UniformSampling("reference")``).
 
 The legacy loops live HERE as fixtures (verbatim from the seed
 implementations, evals included), not in src/: the engine is the only
@@ -15,9 +15,9 @@ import numpy as np
 import pytest
 
 from repro.configs.paper_models import SINE_MLP
-from repro.core import (CommChannel, fedavg_train, fedsgd_train,
-                        reptile_train, tifed_train, tinyreptile_train,
-                        transfer_train)
+from repro.core import (CommChannel, UniformSampling, fedavg_train,
+                        fedsgd_train, reptile_train, tifed_train,
+                        tinyreptile_train, transfer_train)
 from repro.core.engine import _block_runner
 from repro.core.meta import (evaluate_init, finetune_batch, finetune_online,
                              tree_bytes, tree_lerp)
@@ -30,7 +30,7 @@ LOSS = functools.partial(paper_model_loss, SINE_MLP)
 EVAL = dict(num_tasks=4, support=8, k_steps=4, lr=0.02, query=16)
 # the legacy loops draw each task, then its samples: the engine's
 # per-task "reference" sampler, not its default block order
-REF = dict(sampler="reference")
+REF = dict(sampling=UniformSampling("reference"))
 
 
 # ---------------------------------------------------------------------------
@@ -273,7 +273,6 @@ def test_schedule_threaded_engine_is_bitwise_uniform(setup):
     tests above pin the numerics to the seed; this pins the explicit
     schedule object to the default path — the ClientSchedule arrays ride
     the scan but the uniform body must not touch them."""
-    from repro.core import UniformSampling
     params, dist = setup
     cases = [
         (tinyreptile_train, dict(rounds=15, alpha=1.0, beta=0.02,
@@ -339,7 +338,7 @@ def test_tifed_pipelined_matches_sync_bitwise(setup):
     documentedly different block RNG orders)."""
     params, dist = setup
     kw = dict(rounds=16, alpha=1.0, support=16, clients_per_round=4,
-              seed=32, sampler="reference")
+              seed=32, **REF)
     sync = tifed_train(params, dist, prefetch=0, **kw)
     piped = tifed_train(params, dist, prefetch=2, max_block=4, **kw)
     for x, y in zip(jax.tree.leaves(sync["params"]),

@@ -7,8 +7,8 @@ Covers the four tentpole contracts:
   synchronous runs across eval cadences and uneven max_block tails;
 - vectorized block sampling == the scalar block-order reference loop for
   the sine distribution, and shape/dtype contracts for all distributions;
-- the default run's one block call a block, the pool's sampler for a
-  pooled run, and ``sampler=`` refused beside a policy;
+- the default run's one block call a block, and a pooled run's block-path
+  seating with data drawn by the pool's identity;
 - TinyMetaFed-style partial-communication channel (fraction accounting +
   masked uplink) and the block-runner cache counters.
 """
@@ -90,7 +90,8 @@ def test_plan_blocks_rejects_nonpositive_max_block():
 def test_prefetch_parity_across_eval_cadence(setup, eval_every, sampler):
     params, dist = setup
     kw = dict(rounds=21, alpha=1.0, beta=0.02, support=4, seed=3,
-              eval_every=eval_every, eval_kwargs=EVAL, sampler=sampler)
+              eval_every=eval_every, eval_kwargs=EVAL,
+              sampling=UniformSampling(sampler))
     sync = tinyreptile_train(LOSS, params, dist, prefetch=0, **kw)
     piped = tinyreptile_train(LOSS, params, dist, prefetch=2, **kw)
     _assert_trees_equal(sync["params"], piped["params"])
@@ -110,14 +111,12 @@ def test_prefetch_parity_uneven_max_block_tail(setup):
 
 
 def test_sampling_policy_object_param(setup):
-    """An explicit SamplingPolicy instance overrides the sampler string."""
+    """A run with no policy is the run with ``UniformSampling()``."""
     params, dist = setup
-    from repro.core import run_federated
-    from repro.core.strategies import TinyReptileStrategy as S
     kw = dict(rounds=9, alpha=1.0, beta=0.02, support=4, seed=2)
-    a = run_federated(params, dist, S(LOSS), sampler="vectorized", **kw)
-    b = run_federated(params, dist, S(LOSS),
-                      sampling=UniformSampling("vectorized"), **kw)
+    a = run_federated(params, dist, TinyReptileStrategy(LOSS), **kw)
+    b = run_federated(params, dist, TinyReptileStrategy(LOSS),
+                      sampling=UniformSampling(), **kw)
     _assert_trees_equal(a["params"], b["params"])
     with pytest.raises(ValueError):
         UniformSampling("bogus")
@@ -152,7 +151,7 @@ class _CallData(TaskDistribution):
 
 
 def test_default_run_takes_one_block_call_a_block(setup):
-    """Naming no sampler, a run asks the distribution for each block's
+    """Naming no policy, a run asks the distribution for each block's
     data in one call and never for a task; on data served alike to both
     routes it computes the per-task loop's params bit for bit."""
     params, _ = setup
@@ -162,7 +161,7 @@ def test_default_run_takes_one_block_call_a_block(setup):
     block = run_federated(params, block_dist, TinyReptileStrategy(LOSS),
                           **kw)
     loop = run_federated(params, loop_dist, TinyReptileStrategy(LOSS),
-                         sampler="reference", **kw)
+                         sampling=UniformSampling("reference"), **kw)
     assert (block_dist.blocks, block_dist.tasks) == (3, 0)
     assert (loop_dist.blocks, loop_dist.tasks) == (0, 12 * 3)
     _assert_trees_equal(block["params"], loop["params"])
@@ -170,35 +169,29 @@ def test_default_run_takes_one_block_call_a_block(setup):
 
 @pytest.mark.parametrize("pool_sampler", ["reference", "vectorized"])
 def test_pooled_run_follows_the_pool_sampler(setup, pool_sampler):
-    """With a pool and no sampler, the run seats cohorts by the pool's
-    sampler: the same run as naming it, another than naming the other
-    (the pool is large enough beside the cohort for the two seatings to
-    differ)."""
+    """With a pool and no policy, the run seats cohorts through the block
+    path whatever the pool's identity (the same seating as naming
+    ``UniformSampling()``, another than the per-round loop: the pool is
+    large enough beside the cohort for the two to differ), and the pool
+    draws the data by its own identity."""
     params, dist = setup
     other = {"reference": "vectorized", "vectorized": "reference"}
     kw = dict(rounds=6, clients_per_round=3, beta=0.02, support=4, seed=4)
 
-    def run(**extra):
-        pool = ClientPool(dist, 64, seed=1, sampler=pool_sampler)
-        return tinyreptile_train(LOSS, params, dist, pool=pool, **extra,
-                                 **kw)["pool_state"]["checkins"]
-    default = run()
-    np.testing.assert_array_equal(default, run(sampler=pool_sampler))
-    assert not np.array_equal(default, run(sampler=other[pool_sampler]))
-
-
-@pytest.mark.parametrize("sampler", [None, "vectorized", "reference"])
-def test_sampler_beside_a_policy_is_refused(setup, sampler):
-    """A policy carries its own sampler: ``sampler=`` beside it is
-    refused whatever it names, and leaving it unset is not."""
-    params, dist = setup
-    kw = dict(rounds=2, support=4, sampler=sampler,
-              sampling=UniformSampling())
-    if sampler is None:
-        tinyreptile_train(LOSS, params, dist, **kw)
-    else:
-        with pytest.raises(ValueError, match="sampling policy"):
-            tinyreptile_train(LOSS, params, dist, **kw)
+    def run(identity=pool_sampler, **extra):
+        pool = ClientPool(dist, 64, seed=1, sampler=identity)
+        out = tinyreptile_train(LOSS, params, dist, pool=pool, **extra,
+                                **kw)
+        return out["pool_state"]["checkins"], out["params"]
+    seats, params_default = run()
+    np.testing.assert_array_equal(
+        seats, run(sampling=UniformSampling())[0])
+    assert not np.array_equal(
+        seats, run(sampling=UniformSampling("reference"))[0])
+    other_seats, params_other = run(other[pool_sampler])
+    np.testing.assert_array_equal(seats, other_seats)
+    assert not all(np.array_equal(a, b) for a, b in zip(
+        jax.tree.leaves(params_default), jax.tree.leaves(params_other)))
 
 
 # ---------------------------------------------------------------------------
@@ -390,7 +383,8 @@ def test_vectorized_sampler_trains(setup):
     params, dist = setup
     out = tinyreptile_train(LOSS, params, dist, rounds=60, alpha=1.0,
                             beta=0.02, support=8, seed=1, eval_every=60,
-                            eval_kwargs=EVAL, sampler="vectorized")
+                            eval_kwargs=EVAL,
+                            sampling=UniformSampling("vectorized"))
     assert np.isfinite(out["history"][-1]["query_loss"])
     assert all(np.isfinite(np.asarray(l)).all()
                for l in jax.tree.leaves(out["params"]))

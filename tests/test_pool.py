@@ -12,6 +12,8 @@ Covers the tentpole contracts:
   frozen between flushes, flush-every-round degenerating to the
   unbuffered pooled run, staleness discounts favoring fresh updates;
 - Markov / diurnal availability statistics + the no-show-round no-op;
+- one default host data path: every shipped policy takes ClientPool's
+  and, with everyone participating, draws UniformSampling()'s block;
 - the legacy fast path with pool=None stays bit-for-bit (pinned), and
   pooled runs trace exactly once per config.
 """
@@ -25,7 +27,8 @@ import pytest
 from repro.configs.paper_models import SINE_MLP
 from repro.core import (BufferedAggregation, ClientPool, CommChannel,
                         DiurnalAvailability, MarkovAvailability,
-                        PartialParticipation, UniformSampling,
+                        PartialParticipation, StragglerSampling,
+                        UniformSampling,
                         clear_runner_cache, plan_blocks, reptile_train,
                         run_federated, tinyreptile_train)
 from repro.core.engine import _block_runner
@@ -77,12 +80,12 @@ def test_pool_data_depends_only_on_own_checkins():
     clients were scheduled around it."""
     part_a = np.array([[True, True], [True, True]])
     cohort_a = np.array([[2, 5], [2, 1]], np.int32)
-    got_a = ClientPool(SineTasks(), 8, seed=0).sample_cohort_block(
-        cohort_a, part_a, support=4)
+    pool = functools.partial(ClientPool, SineTasks(), 8, seed=0,
+                             sampler="reference")
+    got_a = pool().sample_cohort_block(cohort_a, part_a, support=4)
     part_b = np.array([[True, False], [True, False]])
     cohort_b = np.array([[2, 0], [2, 0]], np.int32)
-    got_b = ClientPool(SineTasks(), 8, seed=0).sample_cohort_block(
-        cohort_b, part_b, support=4)
+    got_b = pool().sample_cohort_block(cohort_b, part_b, support=4)
     np.testing.assert_array_equal(got_a["x"][0, 0], got_b["x"][0, 0])
     np.testing.assert_array_equal(got_a["x"][1, 0], got_b["x"][1, 0])
     # consecutive check-ins advance the client's private stream
@@ -102,6 +105,25 @@ def test_pool_validation():
         UniformSampling().plan_pool_schedule(
             np.random.default_rng(0), 0, 4, clients=8, budget=2,
             pool_size=4)
+
+
+@pytest.mark.parametrize("policy", [
+    UniformSampling(), PartialParticipation(1.0), StragglerSampling(),
+    DiurnalAvailability(), MarkovAvailability(),
+], ids=lambda p: type(p).__name__)
+def test_every_policy_takes_the_pool_default(policy):
+    """Every shipped policy's default sampler is ClientPool's, and with
+    full participation it draws the same block as UniformSampling()
+    from the same generator state."""
+    dist = SineTasks()
+    assert policy.sampler == ClientPool(dist, 4).sampler
+    part = np.ones((3, 4), bool)
+    got, want = (p.sample_block(dist, np.random.default_rng(5), 3, 4, 6,
+                                "batch", participation=part)
+                 for p in (policy, UniformSampling()))
+    assert got.keys() == want.keys()
+    for k in want:
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
 
 
 # ---------------------------------------------------------------------------

@@ -163,7 +163,7 @@ def test_host_caches_stay_bounded():
     assert len(pool._templates) <= _MAX_TEMPLATES
     # the reference pool on the same workload accretes one generator
     # per distinct client ever seated — the liability being removed
-    ref = ClientPool(dist, 100_000, seed=1)
+    ref = ClientPool(dist, 100_000, seed=1, sampler="reference")
     cohort = seat_cohorts(np.random.default_rng(3), 100_000, 64, 4)
     ref.sample_cohort_block(cohort, np.ones(cohort.shape, bool), 2)
     assert len(ref._rngs) == len(np.unique(cohort))
@@ -305,8 +305,8 @@ def test_pool_sampler_resume_mismatch_rejected(tmp_path):
     _run(ClientPool(dist, 9, seed=5, sampler="vectorized"), rounds=6,
          ckpt_dir=d, ckpt_every=3, anneal=False)
     with pytest.raises(ValueError, match="pool_sampler"):
-        _run(ClientPool(dist, 9, seed=5), rounds=12, ckpt_dir=d,
-             ckpt_every=3, resume=True, anneal=False)
+        _run(ClientPool(dist, 9, seed=5, sampler="reference"), rounds=12,
+             ckpt_dir=d, ckpt_every=3, resume=True, anneal=False)
 
 
 def test_million_client_pool_end_to_end():

@@ -27,7 +27,7 @@ import numpy as np
 import pytest
 
 from repro.configs.paper_models import SINE_MLP
-from repro.core import run_federated
+from repro.core import UniformSampling, run_federated
 from repro.core.strategies import TinyReptileStrategy
 from repro.data import SineTasks
 from repro.models.paper_nets import init_paper_model, paper_model_loss
@@ -303,24 +303,24 @@ def test_resume_fingerprint_mismatch_rejected(sine_setup):
 
 
 def test_resume_under_another_sampler_rejected(sine_setup):
-    """The fingerprint records the sampler the run resolved: a snapshot
-    of the per-task sampler (the default before the block path) is
-    refused under another named sampler, and a run that names none
+    """The fingerprint records the policy's sampler: a snapshot of the
+    per-task sampler (the default before the block path) is refused
+    under a policy naming another, and a run that names no policy
     continues it under its own, bit for bit."""
     params, dist, strategy = sine_setup
     kw = dict(beta=0.02, support=6, seed=5, anneal=False)
     with tempfile.TemporaryDirectory() as d:
         run_federated(params, dist, strategy, rounds=2, ckpt_dir=d,
-                      ckpt_every=2, ckpt_async=False, sampler="reference",
-                      **kw)
+                      ckpt_every=2, ckpt_async=False,
+                      sampling=UniformSampling("reference"), **kw)
         with pytest.raises(ValueError, match="policy_sampler"):
             run_federated(params, dist, strategy, rounds=4, ckpt_dir=d,
-                          ckpt_every=2, resume=True, sampler="vectorized",
-                          **kw)
+                          ckpt_every=2, resume=True,
+                          sampling=UniformSampling("vectorized"), **kw)
         resumed = run_federated(params, dist, strategy, rounds=4,
                                 ckpt_dir=d, ckpt_every=2, resume=True, **kw)
         fresh = run_federated(params, dist, strategy, rounds=4,
-                              sampler="reference", **kw)
+                              sampling=UniformSampling("reference"), **kw)
         _exact(fresh["params"], resumed["params"])
 
 
@@ -328,7 +328,7 @@ def test_cli_resumes_a_snapshot_of_the_per_task_default(tmp_path,
                                                         monkeypatch, capsys):
     """A launcher run snapshotted under the per-task sampler (the default
     before the block path) continues from the CLI, which names no
-    sampler, in that order: as a resume that names the sampler does."""
+    policy, in that order: as a resume that names the policy does."""
     import repro.core
     from repro.launch import train
 
@@ -340,7 +340,10 @@ def test_cli_resumes_a_snapshot_of_the_per_task_default(tmp_path,
         row.pop("dt_s")
         return row
 
-    per_task = functools.partial(run_federated, sampler="reference")
+    def per_task(*args, sampling=None, **kw):
+        return run_federated(
+            *args, sampling=sampling or UniformSampling("reference"), **kw)
+
     old, named = tmp_path / "old", tmp_path / "named"
     with monkeypatch.context() as m:
         m.setattr(repro.core, "run_federated", per_task)

@@ -21,7 +21,7 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.configs.paper_models import SINE_MLP
-from repro.core import ClientPool, run_federated
+from repro.core import ClientPool, UniformSampling, run_federated
 from repro.core.strategies import TinyReptileStrategy
 from repro.data import SineTasks
 from repro.data.tasks import TaskDistribution
@@ -84,7 +84,7 @@ def _serve():
 
 @pytest.mark.parametrize("kw,sampler", [
     ({}, "vectorized"),
-    ({"sampler": "reference"}, "reference"),
+    ({"sampling": UniformSampling("reference")}, "reference"),
     ({"pool": ClientPool(SineTasks(), 4)}, "pool"),
     ({"dist": _PerTaskSine()}, "reference"),
 ])

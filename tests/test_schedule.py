@@ -342,24 +342,6 @@ def test_scheduled_runs_trace_once(setup):
 # rotating partial-communication masks
 # ---------------------------------------------------------------------------
 
-def test_sampler_string_conflicts_with_policy_object(setup):
-    """run_federated must not silently ignore a non-default sampler=
-    string when an explicit sampling= policy (with its own sampler)
-    is passed."""
-    params, dist = setup
-    with pytest.raises(ValueError, match="sampling policy"):
-        reptile_train(LOSS, params, dist, rounds=4, beta=0.02, support=4,
-                      sampler="vectorized",
-                      sampling=PartialParticipation(0.5))
-    # default sampler string + policy: fine (the policy's choice wins)
-    out = reptile_train(LOSS, params, dist, rounds=4, beta=0.02, support=4,
-                        sampling=PartialParticipation(
-                            0.5, sampler="vectorized"),
-                        clients_per_round=2, seed=0)
-    assert np.isfinite(np.asarray(
-        jax.tree.leaves(out["params"])[0])).all()
-
-
 def test_rotating_payload_bytes_reports_chunk_not_fraction():
     """For non-reciprocal fractions the rotating wire carries
     1/ceil(1/fraction) of the entries per round, and payload_bytes must
@@ -576,8 +558,8 @@ def test_scheduled_and_uniform_runners_cached_separately():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("policy", [
-    PartialParticipation(0.5),
-    StragglerSampling(0.25),
+    PartialParticipation(0.5, sampler="reference"),
+    StragglerSampling(0.25, sampler="reference"),
     PartialParticipation(0.5, sampler="vectorized"),
 ])
 def test_scheduled_prefetch_parity(setup, policy):
